@@ -41,6 +41,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.api import (AUTO, CONSTANT, DataSource, ExperimentSpec,
                        LINE_SEARCH, LS_MODES, RESIDENT, SEQUENTIAL, SOLVERS,
                        STREAMED, TracePolicy, VECTORIZED, execute, plan)
@@ -670,6 +671,7 @@ if __name__ == "__main__":
         # benchmarking single-host rows labeled as a sharded request
         ap.error(f"--reduction {a.reduction} needs --devices N>1 "
                  f"(it picks how a mesh combines per-device work)")
+    compile_cache.enable()
     rows_n = a.rows or (40_000 if a.adaptive else 100_000)
     epochs_n = a.epochs or (12 if a.adaptive else 3)
     if a.adaptive:

@@ -415,6 +415,8 @@ def main() -> None:
 
 
 if __name__ == '__main__':
+    from repro import compile_cache
+    compile_cache.enable()
     if len(sys.argv) > 1 and sys.argv[1] == "sweep":
         sweep_main(sys.argv[2:])
     elif len(sys.argv) > 1 and sys.argv[1] == "run":

@@ -1,4 +1,6 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU):
+"""Pallas TPU kernels.  On the CPU backend they run in interpret mode,
+which checks their arithmetic; tests/test_tpu_compile.py compiles fused_erm
+for a described v5e chip, and chip_smoke.py runs it on one:
 
   sampled_gather  the paper's contribution at the HBM->VMEM tier
   fused_erm       sampled gather FUSED with the ERM gradient — the epoch

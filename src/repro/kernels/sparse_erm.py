@@ -42,9 +42,10 @@ Semantics contract (tested in ``tests/test_sparse_erm.py``):
 * parity: equals ``fused_batch_grad_data`` on ``CSRCorpus.densify()`` to
   <= 1e-5 for all three losses and all three schemes.
 
-``interpret=None`` auto-selects interpreter mode off-TPU (CPU CI runs the
-same code path a TPU compiles); the host-side scipy/numpy fallbacks for
-streamed full-corpus passes live in ``repro.data.sparse``.
+``interpret=None`` runs the interpreter on the CPU backend only.  These
+kernels have never been compiled for a TPU (they are on no live path); the
+host-side scipy/numpy fallbacks for streamed full-corpus passes live in
+``repro.data.sparse``.
 """
 from __future__ import annotations
 

@@ -119,11 +119,8 @@ def collective_bytes(hlo_text: str, total_devices: int) -> CollectiveStats:
 
 
 def cost_dict(compiled) -> Dict[str, float]:
-    """Normalise compiled.cost_analysis() across jax versions."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0] if ca else {}
-    return {k: float(v) for k, v in dict(ca).items()
+    """The numeric entries of compiled.cost_analysis()."""
+    return {k: float(v) for k, v in compiled.cost_analysis().items()
             if isinstance(v, (int, float, np.floating))}
 
 

@@ -128,6 +128,45 @@ def test_planner_auto_placement_respects_budget(dense_corpus):
     assert p.placement == STREAMED and p.backend == STREAMED_EAGER
 
 
+class _Chip:
+    """A stand-in TPU device with the given memory_stats()."""
+    platform, device_kind = "tpu", "TPU v5 lite"
+
+    def __init__(self, stats):
+        self.stats = stats
+
+    def memory_stats(self):
+        return self.stats
+
+
+@pytest.mark.parametrize("stats", [None, {}, {"bytes_limit": 0},
+                                   {"bytes_limit": 10_000}])
+def test_planner_sizes_a_chip_by_its_own_memory_limit(dense_corpus,
+                                                      monkeypatch, stats):
+    """Auto placement on a TPU reads bytes_limit; a chip that reports none
+    is a PlanError, not an assumed 1 GiB device."""
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: [_Chip(stats)])
+    spec = _spec(DataSource.corpus(dense_corpus))
+    if not (stats or {}).get("bytes_limit"):
+        with pytest.raises(PlanError, match="bytes_limit"):
+            plan(spec)
+    else:   # 0.6 of 10 kB cannot hold the 31 kB corpus
+        assert plan(spec).placement == STREAMED
+
+
+@pytest.mark.parametrize("rows,want", [(ROWS, RESIDENT_FUSED),
+                                       (ROWS + 1, RESIDENT_EAGER)])
+def test_planner_keeps_fused_off_a_corpus_the_kernels_would_pad(
+        monkeypatch, rows, want):
+    """On a TPU the auto kernel is fused only when the corpus needs no
+    per-call padding to the kernels' 8-row groups."""
+    X, y, _ = synth_classification(jax.random.PRNGKey(0), rows, FEATS)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    p = plan(_spec(DataSource.arrays(X, y)))
+    assert p.backend == want
+    assert (want == RESIDENT_FUSED) or any("padded" in w for w in p.why)
+
+
 def test_planner_line_search_lowers_onto_fused_backend(dense_corpus):
     """step='line_search' is no longer a fused-path conflict: forced fused
     kernels plan RESIDENT_FUSED (trial objectives from the fused margin
