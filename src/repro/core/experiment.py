@@ -38,6 +38,7 @@ The four solver entry points (``run`` / ``make_step_fn`` /
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 from functools import partial
@@ -52,8 +53,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from ..checkpoint.checkpointer import (Checkpointer, CheckpointPolicy,
                                        atomic_write_text)
 from ..distributed.sharding import data_parallel_width, make_staging_put
-from ..obs import (ACCESS, COMPUTE, EPOCH, GATHER as GATHER_LANE, H2D,
-                   NULL_TRACER, Timeline, TracePolicy, Tracer)
+from ..obs import (ACCESS, COMPUTE, DRIVER, EPOCH, GATHER as GATHER_LANE,
+                   H2D, NULL_TRACER, Timeline, TracePolicy, Tracer)
 from . import samplers, schemes
 from .erm import ERMProblem, LOGISTIC, SMOOTH_HINGE, SQUARE
 from .solvers import (CONSTANT, LINE_SEARCH, SOLVERS, SolverConfig,
@@ -820,7 +821,7 @@ class RunResult:
         disk, when the spec carries a :class:`CheckpointPolicy`).  Schema 2
         adds ``w``/``train_s``/``compute_s`` so :meth:`from_json` can
         rebuild the full summary surface, per-device stats included;
-        schema 3 adds the ``metrics`` block (counter/gauge/histogram
+        schema 3 adds the ``metrics`` block (counter/histogram
         snapshot of a traced run — ``{}`` untraced; span events stay in
         the separate Chrome-trace artifact, see :meth:`save_trace`)."""
         p = self.plan
@@ -1075,6 +1076,10 @@ def execute(plan_: ExecutionPlan, *, resume: Optional[RunResult] = None,
     state is copied (the stored result stays usable) and the sampler resumes
     at the exact step an uninterrupted run would be at.
     """
+    # first, so the timeline's origin is the call's start: every second of
+    # the call lies on the tracer's clock
+    tracer = (plan_.spec.trace.make_tracer()
+              if plan_.spec.trace is not None else NULL_TRACER)
     epochs = plan_.spec.epochs if epochs is None else epochs
     if resume is not None:
         if resume.solver_state is None:
@@ -1114,19 +1119,32 @@ def execute(plan_: ExecutionPlan, *, resume: Optional[RunResult] = None,
                 + "\n  ".join(diffs
                               or ["(plans compare unequal with no "
                                   "field-level difference)"]))
-    pol = plan_.spec.trace
-    tracer = pol.make_tracer() if pol is not None else NULL_TRACER
-    if plan_.placement == RESIDENT:
-        result = _execute_resident(plan_, resume, epochs, tracer)
-    else:
-        result = _execute_streamed(plan_, resume, epochs, tracer)
+    with _compile_events(tracer):
+        if plan_.placement == RESIDENT:
+            result = _execute_resident(plan_, resume, epochs, tracer)
+        else:
+            result = _execute_streamed(plan_, resume, epochs, tracer)
     if tracer.enabled:
         # the timeline is PER-CALL, like stats: each segment of a resumed
         # run carries (and, below, writes) its own trace
         result.timeline = tracer.timeline()
-        if pol.path is not None:
-            result.timeline.save(pol.path)
+        if plan_.spec.trace.path is not None:
+            result.timeline.save(plan_.spec.trace.path)
     return result
+
+
+@contextlib.contextmanager
+def _compile_events(tracer: Tracer):
+    """While an enabled tracer is live, JAX's trace / compile / cache-load
+    events land on it (``driver:compile``, ``jit.*`` counters)."""
+    if not tracer.enabled:
+        yield
+        return
+    jax.monitoring.register_event_duration_secs_listener(tracer.jax_event)
+    try:
+        yield
+    finally:
+        jax.monitoring.unregister_event_duration_listener(tracer.jax_event)
 
 
 def run_experiment(spec: ExperimentSpec) -> RunResult:
@@ -1220,8 +1238,10 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
 
     if spec.data.kind == ARRAYS:
         if sharded:
-            Xh = np.ascontiguousarray(np.asarray(spec.data.X, np.float32))
-            yh = np.ascontiguousarray(np.asarray(spec.data.y, np.float32))
+            with tracer.span("layout", DRIVER) as sp:
+                Xh = np.ascontiguousarray(np.asarray(spec.data.X, np.float32))
+                yh = np.ascontiguousarray(np.asarray(spec.data.y, np.float32))
+                sp.set(bytes=Xh.nbytes + yh.nbytes)
             X, y, h2d_dt = _stage_resident_sharded(plan_, Xh, yh, stats,
                                                    tracer)
         else:
@@ -1237,8 +1257,10 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
         n = plan_.features
         # contiguity copies BEFORE the timer: device_put of a strided view
         # would hide a host-side memcpy inside the H2D number
-        Xh = np.ascontiguousarray(rows[:, :n])
-        yh = np.ascontiguousarray(rows[:, n])
+        with tracer.span("layout", DRIVER) as sp:
+            Xh = np.ascontiguousarray(rows[:, :n])
+            yh = np.ascontiguousarray(rows[:, n])
+            sp.set(bytes=Xh.nbytes + yh.nbytes)
         if sharded:
             X, y, h2d_dt = _stage_resident_sharded(plan_, Xh, yh, stats,
                                                    tracer)
@@ -1251,6 +1273,11 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
                                               jax.device_put(yh)))
             h2d_dt = sp.dur
             stats.record_h2d(h2d_dt, Xh.nbytes + yh.nbytes)
+        # the host copies are dead once staged: free their pages here, on
+        # the driver's clock, and not unseen at return
+        with tracer.span("release", DRIVER,
+                         bytes=rows.nbytes + Xh.nbytes + yh.nbytes):
+            del rows, Xh, yh
 
     # 'psum' keeps the padded corpus sharded through the scan, so the epoch
     # engine needs the true row count (schedule, clamping, masked snapshot
@@ -1264,28 +1291,34 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
         obj = lambda w: _masked_objective_jit(problem, plan_.rows, w, X, y)
     else:
         obj = lambda w: _objective_jit(problem, w, X, y)
-    state, done0 = _resume_state(plan_, resume)
-    if sharded:
-        # solver state rides the mesh replicated: a fresh (or resumed)
-        # state on the default device would force jit to re-specialize
-        # against the committed corpus shardings
-        state = jax.device_put(  # lint: allow[REPRO002] state placement
-            state, NamedSharding(spec.mesh, PartitionSpec()))
+    with tracer.span("init", DRIVER):
+        state, done0 = _resume_state(plan_, resume)
+        if sharded:
+            # solver state rides the mesh replicated: a fresh (or resumed)
+            # state on the default device would force jit to re-specialize
+            # against the committed corpus shardings
+            # lint: allow[REPRO002] state placement
+            state = jax.device_put(
+                state, NamedSharding(spec.mesh, PartitionSpec()))
 
     if resume is None:
         # compile (epoch fn, embedded snapshot refresh, objective) untimed;
         # a resumed call reuses the original call's jit cache, and paying a
         # full warmup epoch per segment would double the device work of
         # epoch-at-a-time drivers like benchmarks/erm_convergence.py
-        dummy = init_state(cfg.solver, jnp.zeros(plan_.features, jnp.float32),
-                           plan_.num_batches)
-        if sharded:
-            # match the live state's sharding or the warmup compiles a
-            # throwaway specialization
-            dummy = jax.device_put(  # lint: allow[REPRO002] warmup placement
-                dummy, NamedSharding(spec.mesh, PartitionSpec()))
-        jax.block_until_ready(epoch_fn(dummy, X, y, jax.random.PRNGKey(1)).w)
-        jax.block_until_ready(obj(state.w))
+        with tracer.span("warmup", DRIVER):
+            dummy = init_state(cfg.solver,
+                               jnp.zeros(plan_.features, jnp.float32),
+                               plan_.num_batches)
+            if sharded:
+                # match the live state's sharding or the warmup compiles a
+                # throwaway specialization
+                # lint: allow[REPRO002] warmup placement
+                dummy = jax.device_put(
+                    dummy, NamedSharding(spec.mesh, PartitionSpec()))
+            jax.block_until_ready(
+                epoch_fn(dummy, X, y, jax.random.PRNGKey(1)).w)
+            jax.block_until_ready(obj(state.w))
 
     # the epoch key schedule is pure in (seed, epoch index): replaying the
     # splits makes a resumed run use the batch schedule the uninterrupted
@@ -1328,7 +1361,8 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
                 # staging count)
                 stats.record_h2d_saved(h2d_dt)
             if spec.record_objective:
-                history.append(float(obj(state.w)))     # outside the timers
+                with tracer.span("objective", DRIVER, epoch=done0 + e):
+                    history.append(float(obj(state.w)))
             rck.after_epoch(e, state,
                             {"scheme": plan_.scheme_name, "seed": spec.seed,
                              "epochs": done0 + e + 1},
@@ -1336,7 +1370,11 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
     finally:
         rck.finish()
 
-    objective = history[-1] if history else float(obj(state.w))
+    if history:
+        objective = history[-1]
+    else:
+        with tracer.span("objective", DRIVER, epoch=done0 + epochs - 1):
+            objective = float(obj(state.w))
     return RunResult(
         plan=plan_, objective=objective,
         history=np.asarray(prefix + history),
@@ -1357,7 +1395,8 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
     problem = spec.problem
     m, K, n = plan_.num_batches, plan_.chunk, plan_.features
     b = spec.batch_size
-    state, done0 = _resume_state(plan_, resume)
+    with tracer.span("init", DRIVER):
+        state, done0 = _resume_state(plan_, resume)
     start_step = done0 * m
     scheme_obj = plan_.scheme_obj
     adaptive = scheme_obj.adaptive
@@ -1377,10 +1416,11 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
                                   prefetch=0 if adaptive else spec.prefetch)
     if plan_.fmt == CSR:
         from ..data import sparse
-        csr = sparse.open_csr_corpus(spec.data.path)
+        with tracer.span("init", DRIVER):
+            csr = sparse.open_csr_corpus(spec.data.path)
+            pipe = sparse.SparsePipeline(pcfg, start_step=start_step,
+                                         tracer=tracer, sampler_meta=smeta)
         kmax = plan_.kmax if plan_.kmax else csr.kmax
-        pipe = sparse.SparsePipeline(pcfg, start_step=start_step,
-                                     tracer=tracer, sampler_meta=smeta)
 
         def alloc(k):
             return (np.empty((k, b, kmax), np.int32),
@@ -1406,11 +1446,14 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
             means, _ = sparse.csr_block_losses(problem, csr, np.asarray(w),
                                                b)
             return {"block_losses": means}
+
+        objective_args = {}
     else:
         from ..data import dataset
-        mm, _ = dataset.open_corpus(spec.data.path)
-        pipe = pipemod.DataPipeline(pcfg, start_step=start_step,
-                                    tracer=tracer, sampler_meta=smeta)
+        with tracer.span("init", DRIVER):
+            mm, _ = dataset.open_corpus(spec.data.path)
+            pipe = pipemod.DataPipeline(pcfg, start_step=start_step,
+                                        tracer=tracer, sampler_meta=smeta)
 
         def alloc(k):
             return (np.empty((k, b, n), np.float32),
@@ -1459,8 +1502,12 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
                 lo += Xc.shape[0]
             return {"block_losses": sums / np.maximum(cnt, 1)}
 
+        # what one dense objective pass reads from the memmap
+        objective_args = {"rows": plan_.rows,
+                          "chunks": -(-plan_.rows // _EVAL_CHUNK),
+                          "bytes": plan_.rows * mm.shape[1] * mm.itemsize}
+
     sharded = plan_.shards > 1
-    eval_fn = eval_obj if spec.record_objective else None
     if sharded:
         # chunk staging shards the batch axis across the mesh; js (the
         # batch-slot indices) replicates.  The CSR layout never gets here —
@@ -1487,28 +1534,37 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
                                  + ((jnp.ones((k,), jnp.float32),)
                                     if adaptive else ()))
         host_w = lambda w: w
-    if eval_fn is not None:
-        inner_eval = eval_fn
-        eval_fn = lambda w: inner_eval(host_w(w))
 
-    # compile every chunk shape outside the timed region
-    for k in sorted({K, m % K} - {0}):
-        dummy = init_state(cfg.solver, jnp.zeros(n, jnp.float32), m)
-        if sharded:
-            # lint: allow[REPRO002] warmup placement
-            dummy = jax.device_put(dummy, rep)
-        jax.block_until_ready(epoch_fn(dummy, *stage_zeros(k)))
+    def objective(w, epoch):
+        """The objective pass after ``epoch``, as a ``driver:objective``
+        span carrying what a dense pass reads."""
+        with tracer.span("objective", DRIVER, epoch=epoch, **objective_args):
+            return eval_obj(host_w(w))
+
+    eval_fn = objective if spec.record_objective else None
 
     snapshot_begin = None
+    data_only = cfg.solver == "saag2"
+    with tracer.span("warmup", DRIVER):
+        # compile every chunk shape outside the timed region
+        for k in sorted({K, m % K} - {0}):
+            dummy = init_state(cfg.solver, jnp.zeros(n, jnp.float32), m)
+            if sharded:
+                # lint: allow[REPRO002] warmup placement
+                dummy = jax.device_put(dummy, rep)
+            jax.block_until_ready(epoch_fn(dummy, *stage_zeros(k)))
+        if cfg.solver in ("svrg", "saag2"):
+            # the snapshot full-grad stream compiles too — keep it out of
+            # epoch 1
+            jax.block_until_ready(full_grad_at(jnp.zeros(n, jnp.float32),
+                                               data_term_only=data_only))
     if cfg.solver in ("svrg", "saag2"):
-        data_only = cfg.solver == "saag2"
-        # the snapshot full-grad stream compiles too — keep it out of epoch 1
-        jax.block_until_ready(full_grad_at(jnp.zeros(n, jnp.float32),
-                                           data_term_only=data_only))
         def snapshot_begin(st):
-            st = epoch_begin(problem, cfg, st,
-                             lambda w: full_grad_at(host_w(w),
-                                                    data_term_only=data_only))
+            with tracer.span("snapshot", DRIVER):
+                st = epoch_begin(
+                    problem, cfg, st,
+                    lambda w: full_grad_at(host_w(w),
+                                           data_term_only=data_only))
             # keep every state leaf on the mesh: a default-device snapshot
             # gradient would make the donated epoch call re-specialize
             # lint: allow[REPRO002] snapshot-state mesh placement
@@ -1549,7 +1605,8 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
     finally:
         rck.finish()
 
-    objective = history[-1] if history else eval_obj(host_w(state.w))
+    objective = (history[-1] if history
+                 else objective(state.w, done0 + epochs - 1))
     return RunResult(
         plan=plan_, objective=objective,
         history=np.asarray(prefix + history),
@@ -1579,8 +1636,9 @@ def _drive_chunked(pipe, epoch_fn, state, *, m: int, K: int, epochs: int,
 
     ``alloc(k)`` builds contiguous host staging buffers for a k-batch chunk
     (batches are written straight in — one copy, not stack-then-slice);
-    ``fill(bufs, i, batch)`` writes batch i; ``eval_fn(w)`` is the per-epoch
-    objective probe, run OUTSIDE the timers; ``on_epoch(e, state, history)``
+    ``fill(bufs, i, batch)`` writes batch i; ``eval_fn(w, epoch)`` is the
+    per-epoch objective probe, run OUTSIDE the timers (it opens its own
+    ``driver:objective`` span); ``on_epoch(e, state, history)``
     is the checkpoint hook, also untimed, called at every epoch boundary.
     Returns (state, history, compute_s, train_s).
 
@@ -1654,7 +1712,7 @@ def _drive_chunked(pipe, epoch_fn, state, *, m: int, K: int, epochs: int,
                     done += args[0].shape[0]
             train_s += se.dur
             if eval_fn is not None:
-                history.append(float(eval_fn(state.w)))   # untimed
+                history.append(float(eval_fn(state.w, epoch0 + e)))
             if on_epoch is not None:
                 on_epoch(e, state, history)               # untimed
     finally:
@@ -1734,9 +1792,10 @@ def _drive_chunked_adaptive(pipe, epoch_fn, state, *, m: int, K: int,
             stager.close()   # producer joined: the sampler is quiescent
             train_s += se.dur
             if eval_fn is not None:
-                history.append(float(eval_fn(state.w)))   # untimed
+                history.append(float(eval_fn(state.w, epoch0 + e)))
             if feedback is not None:
-                pipe.observe(feedback(state.w))           # untimed
+                with tracer.span("feedback", DRIVER, epoch=epoch0 + e):
+                    pipe.observe(feedback(state.w))       # untimed
             if on_epoch is not None:
                 on_epoch(e, state, history)               # untimed
     finally:

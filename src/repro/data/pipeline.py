@@ -31,7 +31,7 @@ from typing import Dict, Iterator, Optional, Tuple, Union
 import numpy as np
 
 from ..core import samplers, schemes
-from ..obs import ACCESS, H2D, NULL_TRACER
+from ..obs import ACCESS, H2D, NULL_TRACER, WAIT
 from .dataset import CorpusMeta, host_shard, open_corpus
 
 
@@ -354,7 +354,10 @@ class DeviceStager:
 
     H2D time/bytes are recorded into ``stats`` (an :class:`AccessStats`)
     alongside the disk-access numbers, giving the benchmark its
-    access/H2D/compute breakdown.
+    access/H2D/compute breakdown.  The consumer's wait for each staged
+    item is a ``wait:chunk`` span; an enabled tracer also counts items
+    taken (``stager.gets``) and takes that found the queue empty
+    (``stager.starved``).
 
     **Mesh-aware staging**: pass ``mesh=`` (and ``batch_axes=``, the logical
     axes of each staged array, e.g. ``(None, "batch", None)`` for a
@@ -447,14 +450,10 @@ class DeviceStager:
         self._thread.start()
         try:
             while True:
-                try:
-                    item = self._q.get(timeout=0.1)
-                except queue.Empty:
-                    # close() may have drained the DONE sentinel out from
-                    # under a live consumer; don't block on a dead producer
-                    if self._stop.is_set():
-                        return
-                    continue
+                with self.tracer.span("chunk", WAIT):
+                    item = self._get()
+                if item is _STAGER_STOPPED:
+                    return
                 if item is _STAGER_DONE:
                     if self._err is not None:
                         raise self._err
@@ -462,6 +461,26 @@ class DeviceStager:
                 yield item
         finally:
             self.close()
+
+    def _get(self):
+        """The next staged item, blocking until one arrives: the DONE
+        sentinel once the producer has finished, STOPPED once close() has
+        run with the queue empty."""
+        tracer = self.tracer
+        if tracer.enabled and self._q.empty():
+            tracer.metrics.counter("stager.starved").inc()
+        while True:
+            try:
+                item = self._q.get(timeout=0.1)
+            except queue.Empty:
+                # close() may have drained the DONE sentinel out from
+                # under a live consumer; don't block on a dead producer
+                if self._stop.is_set():
+                    return _STAGER_STOPPED
+                continue
+            if tracer.enabled and item is not _STAGER_DONE:
+                tracer.metrics.counter("stager.gets").inc()
+            return item
 
     def close(self):
         self._stop.set()
@@ -477,3 +496,4 @@ class DeviceStager:
 
 
 _STAGER_DONE = object()
+_STAGER_STOPPED = object()

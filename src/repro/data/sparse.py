@@ -417,10 +417,15 @@ class SparsePipeline(PrefetchPipeline):
                           + y.nbytes)
             sp.set(bytes=nbytes, nnz=nnz)
         self.stats.record(sp.dur, nbytes)
+        bmax = self.cfg.batch_size
+        if self.tracer.enabled:
+            # ELL fill: the nonzeros a staged (bmax, kmax) batch carries
+            # against the slots it occupies
+            self.tracer.metrics.counter("ell.nonzeros").inc(nnz)
+            self.tracer.metrics.counter("ell.slots").inc(bmax * self.kmax)
         with self.tracer.span("ell_pad", CONVERT, nnz=nnz):
             cols, vals = _pad_segments(fc, fv, lens, offs, self.kmax)
             y = y.astype(np.float32)
-            bmax = self.cfg.batch_size
             if b < bmax:
                 # variable-size scheme: pad the ROW count back to the static
                 # staged shape with all-zero rows (zero features and zero

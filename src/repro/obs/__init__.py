@@ -5,12 +5,13 @@ Public surface: :class:`Tracer` (span recorder), :data:`NULL_TRACER`
 (the ``ExperimentSpec.trace`` knob), :class:`Timeline` (the snapshot on
 ``RunResult.timeline``), the lane constants, and the metrics primitives.
 """
-from .metrics import Counter, Gauge, Histogram, Metrics, NullMetrics
+from .metrics import Counter, Histogram, Metrics, NullMetrics
 from .trace import (
     ACCESS,
     CHECKPOINT,
     COMPUTE,
     CONVERT,
+    DRIVER,
     EPOCH,
     GATHER,
     H2D,
@@ -20,6 +21,7 @@ from .trace import (
     TracePolicy,
     Tracer,
     Timeline,
+    WAIT,
 )
 
 __all__ = [
@@ -27,13 +29,13 @@ __all__ = [
     "CHECKPOINT",
     "COMPUTE",
     "CONVERT",
+    "DRIVER",
     "EPOCH",
     "GATHER",
     "H2D",
     "LANES",
     "NULL_TRACER",
     "Counter",
-    "Gauge",
     "Histogram",
     "Metrics",
     "NullMetrics",
@@ -41,4 +43,5 @@ __all__ = [
     "TracePolicy",
     "Tracer",
     "Timeline",
+    "WAIT",
 ]

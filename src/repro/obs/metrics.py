@@ -1,15 +1,14 @@
-"""Counters / gauges / histograms registry for the tracing subsystem.
+"""Counters / histograms registry for the tracing subsystem.
 
 The paper's accounting identity (training time = access time + compute
 time) needs more than totals to act on: WHERE the access seconds
 concentrate is a distribution question (one slow wrap-around read vs a
 uniformly slow storage path look identical in a sum).  This module keeps
-that distribution observable with three primitive families, all
+that distribution observable with two primitive families, both
 zero-dependency and thread-safe:
 
-* :class:`Counter` — monotonically increasing totals (batches staged,
-  line-search invocations, checkpoint saves).
-* :class:`Gauge` — last-written values (mesh width, chunk shape).
+* :class:`Counter` — monotonically increasing totals (line-search
+  invocations, stager gets, ELL slots, JIT compiles).
 * :class:`Histogram` — per-phase duration distributions over a bounded
   reservoir, snapshot as count/sum/max/p50/p95 — the per-phase measured
   timings the ROADMAP's cost-model planner consumes as ground truth.
@@ -17,7 +16,7 @@ zero-dependency and thread-safe:
 A :class:`Metrics` registry owns one namespace of each and snapshots to a
 plain JSON-safe dict (the ``metrics`` block of ``RunResult.to_json``).
 The tracer feeds one histogram per span lane+name automatically; callers
-add counters/gauges explicitly where a quantity is not a duration.
+add counters explicitly where a quantity is not a duration.
 """
 from __future__ import annotations
 
@@ -44,20 +43,6 @@ class Counter:
     def inc(self, n: int = 1) -> None:
         with self._lock:
             self.value += n
-
-
-class Gauge:
-    """Last-written value."""
-
-    __slots__ = ("value", "_lock")
-
-    def __init__(self):
-        self.value = 0.0
-        self._lock = threading.Lock()
-
-    def set(self, v: float) -> None:
-        with self._lock:
-            self.value = float(v)
 
 
 class Histogram:
@@ -105,7 +90,7 @@ class Histogram:
 
 
 class Metrics:
-    """Thread-safe registry of named counters/gauges/histograms.
+    """Thread-safe registry of named counters and histograms.
 
     Names are free-form dotted strings (``"access.read"``,
     ``"ls.invocations"``); the first access under a name creates the
@@ -116,7 +101,6 @@ class Metrics:
     def __init__(self, window: int = DEFAULT_WINDOW):
         self._window = window
         self._counters: Dict[str, Counter] = {}
-        self._gauges: Dict[str, Gauge] = {}
         self._histograms: Dict[str, Histogram] = {}
         self._lock = threading.Lock()
 
@@ -127,13 +111,6 @@ class Metrics:
                 c = self._counters[name] = Counter()
             return c
 
-    def gauge(self, name: str) -> Gauge:
-        with self._lock:
-            g = self._gauges.get(name)
-            if g is None:
-                g = self._gauges[name] = Gauge()
-            return g
-
     def histogram(self, name: str) -> Histogram:
         with self._lock:
             h = self._histograms.get(name)
@@ -142,23 +119,21 @@ class Metrics:
             return h
 
     def snapshot(self) -> Dict[str, Dict]:
-        """JSON-safe view: {"counters": {...}, "gauges": {...},
+        """JSON-safe view: {"counters": {...},
         "histograms": {name: {count, sum, max, p50, p95}}}.  Safe to call
         while other threads keep observing (each instrument locks
         itself)."""
         with self._lock:
             counters = dict(self._counters)
-            gauges = dict(self._gauges)
             hists = dict(self._histograms)
         return {
             "counters": {k: c.value for k, c in sorted(counters.items())},
-            "gauges": {k: g.value for k, g in sorted(gauges.items())},
             "histograms": {k: h.snapshot() for k, h in sorted(hists.items())},
         }
 
 
 class _NullInstrument:
-    """Shared no-op counter/gauge/histogram for the disabled tracer — every
+    """Shared no-op counter/histogram for the disabled tracer — every
     mutator is a constant-time early return, so instrumentation sites never
     branch on enablement themselves."""
 
@@ -166,9 +141,6 @@ class _NullInstrument:
     value = 0
 
     def inc(self, n: int = 1) -> None:
-        pass
-
-    def set(self, v: float) -> None:
         pass
 
     def observe(self, v: float) -> None:
@@ -187,11 +159,8 @@ class NullMetrics(Metrics):
     def counter(self, name: str):  # type: ignore[override]
         return _NULL_INSTRUMENT
 
-    def gauge(self, name: str):  # type: ignore[override]
-        return _NULL_INSTRUMENT
-
     def histogram(self, name: str):  # type: ignore[override]
         return _NULL_INSTRUMENT
 
     def snapshot(self) -> Dict[str, Dict]:
-        return {"counters": {}, "gauges": {}, "histograms": {}}
+        return {"counters": {}, "histograms": {}}

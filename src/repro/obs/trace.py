@@ -28,8 +28,15 @@ Design constraints, in order:
   truncated timeline is visible, never silent.
 * **Thread-per-lane export.**  Chrome trace ``tid`` is the lane, not the
   OS thread: access / h2d / compute / checkpoint / gather (+ the epoch
-  structure lane), so the producer thread's reads, the stager's copies
-  and the main thread's device calls render as parallel swimlanes.
+  structure, driver and wait lanes), so the producer thread's reads, the
+  stager's copies and the main thread's device calls render as parallel
+  swimlanes.
+* **On the profiler's clock too.**  A tracer built with ``annotate=`` (a
+  ``name -> context manager`` factory; :meth:`TracePolicy.make_tracer`
+  injects ``jax.profiler.TraceAnnotation``) opens one annotation named
+  ``<lane>:<name>`` around every recorded span, so a profile captured
+  around ``execute()`` shows the program's phases beside the device ops.
+  The disabled tracer never touches it, and this module never imports jax.
 """
 from __future__ import annotations
 
@@ -40,7 +47,7 @@ import threading
 import time
 from collections import deque
 from pathlib import Path
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Callable, ContextManager, Dict, List, Optional, Tuple
 
 from .metrics import Metrics, NullMetrics
 
@@ -55,10 +62,22 @@ CONVERT = "convert"        # host-side batch formatting (e.g. CSR->ELL pad);
 #                            NOT booked into AccessStats, so it gets its own
 #                            lane — the accounting lanes above stay exactly
 #                            the measurements stats books
-LANES: Tuple[str, ...] = (EPOCH, ACCESS, CONVERT, H2D, GATHER, COMPUTE,
-                          CHECKPOINT)
+DRIVER = "driver"          # execute()'s phases outside the training epochs:
+#                            init, layout copies, warm-up, objective
+#                            passes, compile events
+WAIT = "wait"              # the epoch engine blocked on the DeviceStager
+LANES: Tuple[str, ...] = (DRIVER, EPOCH, ACCESS, CONVERT, H2D, GATHER,
+                          COMPUTE, WAIT, CHECKPOINT)
 
 DEFAULT_BUFFER = 1 << 16
+
+#: the jax.monitoring events :meth:`Tracer.jax_event` books, by counter
+#: name (``jit.<stage>``)
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "traces",
+    "/jax/core/compile/backend_compile_duration": "compiles",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_loads",
+}
 
 
 class TraceEvent:
@@ -116,7 +135,8 @@ class _Span:
     (the :meth:`Tracer.timespan` disabled path) still measures ``dur`` —
     the caller books it into AccessStats — but appends nothing."""
 
-    __slots__ = ("tracer", "name", "lane", "args", "record", "t0", "dur")
+    __slots__ = ("tracer", "name", "lane", "args", "record", "t0", "dur",
+                 "ann")
 
     def __init__(self, tracer: "Tracer", name: str, lane: str,
                  args: Dict, record: bool):
@@ -127,6 +147,7 @@ class _Span:
         self.record = record
         self.t0 = 0.0
         self.dur = 0.0
+        self.ann = None
 
     def set(self, **args) -> None:
         """Attach attributes discovered inside the span (byte counts,
@@ -136,12 +157,17 @@ class _Span:
     def __enter__(self):
         if self.record:
             self.tracer._push(self.name, self.lane)
+            if self.tracer.annotate is not None:
+                self.ann = self.tracer.annotate(f"{self.lane}:{self.name}")
+                self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.dur = time.perf_counter() - self.t0
         if self.record:
+            if self.ann is not None:
+                self.ann.__exit__(*exc)
             parent, toplevel = self.tracer._pop(self.lane)
             self.tracer._append(self.name, self.lane, self.t0, self.dur,
                                 self.args, parent, toplevel)
@@ -160,12 +186,17 @@ class Tracer:
     :meth:`Timeline.lane_totals` never double-counts.
 
     Every recorded event also feeds a ``span_s.<lane>.<name>`` histogram
-    on ``metrics`` (p50/p95/max per phase come for free).
+    on ``metrics`` (p50/p95/max per phase come for free).  ``annotate``
+    (``name -> context manager``) wraps every recorded span in a profiler
+    annotation named ``<lane>:<name>``; :meth:`jax_event` books JAX's
+    compile events (``driver:compile`` plus the ``jit.*`` counters).
     """
 
     def __init__(self, enabled: bool = True, buffer: int = DEFAULT_BUFFER,
-                 metrics: Optional[Metrics] = None):
+                 metrics: Optional[Metrics] = None,
+                 annotate: Optional[Callable[[str], ContextManager]] = None):
         self.enabled = enabled
+        self.annotate = annotate if enabled else None
         self.epoch = time.perf_counter()
         self.metrics = metrics if metrics is not None else (
             Metrics() if enabled else NullMetrics())
@@ -229,6 +260,18 @@ class Tracer:
         toplevel = not any(l == lane for _, l in st)
         self._append(name, lane, t0, dur, args, parent, toplevel)
 
+    def jax_event(self, event: str, duration: float, **_) -> None:
+        """A ``jax.monitoring`` duration listener: each trace, backend
+        compile or persistent-cache load becomes a ``driver:compile`` event
+        (ending now, ``stage=`` naming the step) and bumps its ``jit.*``
+        counter.  Other events are ignored."""
+        stage = _COMPILE_STAGES.get(event)
+        if stage is None or not self.enabled:
+            return
+        self.metrics.counter(f"jit.{stage}").inc()
+        self.event("compile", DRIVER, t0=time.perf_counter() - duration,
+                   dur=duration, stage=stage)
+
     # ---- extraction -----------------------------------------------------
     def timeline(self) -> "Timeline":
         """Snapshot the ring buffer + metrics into an immutable
@@ -237,7 +280,7 @@ class Tracer:
             events = list(self._events)
             dropped = self.dropped
         return Timeline(events=events, metrics=self.metrics.snapshot(),
-                        dropped=dropped)
+                        dropped=dropped, origin_s=self.epoch)
 
 
 #: process-wide disabled tracer — the default every instrumented layer
@@ -251,10 +294,13 @@ class Timeline:
     call ran — the same basis as ``RunResult.stats``), plus the metrics
     snapshot taken with it.  ``dropped`` counts ring-buffer evictions:
     a nonzero value means ``lane_totals`` undercounts and
-    ``verify_timeline`` will refuse to reconcile."""
+    ``verify_timeline`` will refuse to reconcile.  ``origin_s`` is the
+    tracer's epoch on ``time.perf_counter``: event ``ts`` plus it places a
+    span on the process's clock (None for a timeline rebuilt from JSON)."""
     events: List[TraceEvent]
     metrics: Dict = dataclasses.field(default_factory=dict)
     dropped: int = 0
+    origin_s: Optional[float] = None
 
     def lane_totals(self) -> Dict[str, float]:
         """Summed span seconds per lane, counting only TOPLEVEL spans of
@@ -327,26 +373,6 @@ class Timeline:
                         f"numeric ts and non-negative dur")
         return d
 
-    def merged(self, later: "Timeline", gap: float = 1e-3) -> "Timeline":
-        """Concatenate ``later`` after this timeline on one clock: the
-        later events shift so their first span starts ``gap`` seconds
-        after this timeline's last end (segment traces from resumed runs
-        share no epoch, so wall-clock concatenation is the only honest
-        composition)."""
-        if not self.events:
-            return later
-        if not later.events:
-            return self
-        end = max(e.ts + e.dur for e in self.events)
-        start = min(e.ts for e in later.events)
-        shift = end + gap - start
-        shifted = [TraceEvent(e.name, e.lane, e.ts + shift, e.dur,
-                              dict(e.args), e.parent, e.toplevel)
-                   for e in later.events]
-        return Timeline(events=self.events + shifted,
-                        metrics=later.metrics,
-                        dropped=self.dropped + later.dropped)
-
 
 @dataclasses.dataclass(frozen=True)
 class TracePolicy:
@@ -378,5 +404,10 @@ class TracePolicy:
                 f"trace.enabled must be a bool (got {self.enabled!r})")
 
     def make_tracer(self) -> Tracer:
-        return (Tracer(enabled=True, buffer=self.buffer)
-                if self.enabled else NULL_TRACER)
+        """An enabled policy's tracer annotates every span for the JAX
+        profiler; jax is imported here, not by this module."""
+        if not self.enabled:
+            return NULL_TRACER
+        import jax
+        return Tracer(enabled=True, buffer=self.buffer,
+                      annotate=jax.profiler.TraceAnnotation)

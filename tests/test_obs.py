@@ -23,8 +23,9 @@ import time
 
 import pytest
 
-from repro.obs import (ACCESS, COMPUTE, EPOCH, H2D, LANES, NULL_TRACER,
-                       Metrics, NullMetrics, TracePolicy, Tracer, Timeline)
+from repro.obs import (ACCESS, COMPUTE, DRIVER, EPOCH, H2D, LANES,
+                       NULL_TRACER, Metrics, NullMetrics, TracePolicy, Tracer,
+                       Timeline)
 
 
 # ----------------------------------------------------------- tracer core ----
@@ -126,6 +127,87 @@ def test_tracer_is_thread_safe_under_concurrent_spans():
     assert all(e.toplevel for e in tl.events)  # stacks are per-thread
 
 
+class _Annotations:
+    """A fake ``jax.profiler.TraceAnnotation``: records (what, name)."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+
+        return _Ann()
+
+
+def test_disabled_tracer_never_calls_the_annotation_factory():
+    ann = _Annotations()
+    t = Tracer(enabled=False, annotate=ann)
+    with t.span("objective", DRIVER):
+        pass
+    with t.timespan("read", ACCESS):
+        pass
+    t.event("compile", DRIVER, t0=0.0, dur=1.0)
+    assert ann.log == [] and t.timeline().events == []
+    assert NULL_TRACER.annotate is None
+    assert TracePolicy(enabled=False).make_tracer().annotate is None
+
+
+def test_enabled_tracer_annotates_each_recorded_span_around_its_body():
+    ann = _Annotations()
+    t = Tracer(annotate=ann)
+    with t.span("warmup", DRIVER):
+        with t.timespan("read", ACCESS):
+            ann.log.append(("body", None))
+    t.event("compile", DRIVER, t0=time.perf_counter(), dur=0.0)
+    assert ann.log == [("enter", "driver:warmup"), ("enter", "access:read"),
+                       ("body", None), ("exit", "access:read"),
+                       ("exit", "driver:warmup")]
+    assert len(t.timeline().events) == 3
+
+
+def test_timeline_origin_places_spans_on_perf_counter():
+    before = time.perf_counter()
+    t = Tracer()
+    time.sleep(0.002)
+    t0 = time.perf_counter()
+    with t.span("layout", DRIVER):
+        time.sleep(0.002)
+    t1 = time.perf_counter()
+    tl = t.timeline()
+    (ev,) = tl.events
+    assert before <= tl.origin_s == t.epoch <= t0
+    assert t0 <= tl.origin_s + ev.ts
+    assert tl.origin_s + ev.ts + ev.dur <= t1
+
+
+def test_jax_event_books_compile_stages_on_the_driver_lane():
+    t = Tracer()
+    t.jax_event("/jax/core/compile/jaxpr_trace_duration", 0.25)
+    t.jax_event("/jax/core/compile/backend_compile_duration", 0.5)
+    t.jax_event("/jax/compilation_cache/cache_retrieval_time_sec", 0.125)
+    t.jax_event("/jax/core/compile/jaxpr_to_mlir_module_duration", 1.0)
+    now = time.perf_counter() - t.epoch
+    tl = t.timeline()
+    assert [(e.lane, e.name, e.args["stage"], e.dur) for e in tl.events] == [
+        (DRIVER, "compile", "traces", 0.25),
+        (DRIVER, "compile", "compiles", 0.5),
+        (DRIVER, "compile", "cache_loads", 0.125)]
+    assert all(e.ts + e.dur <= now for e in tl.events)
+    assert tl.metrics["counters"] == {"jit.traces": 1, "jit.compiles": 1,
+                                      "jit.cache_loads": 1}
+    off = Tracer(enabled=False)
+    off.jax_event("/jax/core/compile/jaxpr_trace_duration", 0.25)
+    assert off.timeline().events == [] and off.metrics.snapshot() == {
+        "counters": {}, "histograms": {}}
+
+
 # --------------------------------------------------------- chrome export ----
 
 def test_chrome_export_is_valid_and_microsecond_scaled(tmp_path):
@@ -169,32 +251,18 @@ def test_load_chrome_rejects_malformed_documents(tmp_path):
         Timeline.load_chrome(bad)
 
 
-def test_merged_concatenates_resumed_segments():
-    a = Tracer()
-    with a.span("e0", EPOCH):
-        time.sleep(0.001)
-    b = Tracer()
-    with b.span("e1", EPOCH):
-        time.sleep(0.001)
-    m = a.timeline().merged(b.timeline())
-    assert [e.name for e in m.events] == ["e0", "e1"]
-    ts = [e.ts for e in m.events]
-    assert ts == sorted(ts) and ts[1] >= m.events[0].dur  # shifted past seg 0
-
-
 # ---------------------------------------------------------------- metrics ----
 
 def test_metrics_counters_gauges_and_histograms_snapshot():
     m = Metrics()
     m.counter("ls.invocations").inc(3)
     m.counter("ls.invocations").inc()
-    m.gauge("queue_depth").set(7)
     h = m.histogram("span_s.access.read")
     for v in range(1, 101):
         h.observe(float(v))
     snap = m.snapshot()
     assert snap["counters"]["ls.invocations"] == 4
-    assert snap["gauges"]["queue_depth"] == 7
+    assert set(snap) == {"counters", "histograms"}
     hist = snap["histograms"]["span_s.access.read"]
     assert hist["count"] == 100 and hist["max"] == 100.0
     assert 45 <= hist["p50"] <= 55 and 90 <= hist["p95"] <= 100
@@ -213,9 +281,8 @@ def test_histogram_window_bounds_percentiles_but_not_totals():
 def test_null_metrics_accepts_everything_and_snapshots_empty():
     nm = NullMetrics()
     nm.counter("a").inc(5)
-    nm.gauge("b").set(1)
     nm.histogram("c").observe(0.1)
-    assert nm.snapshot() == {"counters": {}, "gauges": {}, "histograms": {}}
+    assert nm.snapshot() == {"counters": {}, "histograms": {}}
 
 
 def test_tracer_feeds_span_histograms():
