@@ -63,6 +63,11 @@ class ERMProblem:
         vectorized line search and the fused margin kernels share."""
         return jnp.mean(_margin_losses(self.loss)(z, yb))
 
+    def sum_margin_loss(self, z: jax.Array, yb: jax.Array) -> jax.Array:
+        """Summed per-example loss from precomputed margins: the streamed
+        objective pass adds these up block by block."""
+        return jnp.sum(_margin_losses(self.loss)(z, yb))
+
     def data_objective(self, w: jax.Array, Xb: jax.Array, yb: jax.Array) -> jax.Array:
         """Loss term only (no regularizer) — SAAG-II treats the reg exactly."""
         return self.mean_margin_loss(Xb @ w, yb)

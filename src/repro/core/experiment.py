@@ -43,7 +43,7 @@ import dataclasses
 import json
 from functools import partial
 from pathlib import Path
-from typing import Callable, Dict, List, Optional, Tuple, Union
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -90,7 +90,7 @@ DEFAULT_RESIDENT_BUDGET = 1 << 30
 # historical default)
 _CHUNK_BYTE_BUDGET = 64 << 20
 _STEP_SAMPLE_ROWS = 4096       # rows sampled for the auto 1/L step size
-_EVAL_CHUNK = 8192             # rows per streamed objective/gradient chunk
+_EVAL_CHUNK = 8192             # rows per snapshot-gradient/block-loss chunk
 
 
 class PlanError(ValueError):
@@ -1182,6 +1182,58 @@ def _masked_objective_jit(problem: ERMProblem, rows: int, w: jax.Array,
     return problem.masked_objective(w, X, y, rows)
 
 
+@partial(jax.jit, static_argnames=("problem", "n"))
+def _block_loss_sum(problem: ERMProblem, n: int, w: jax.Array,
+                    block: jax.Array, total: jax.Array) -> jax.Array:
+    """``total`` plus the summed data loss of one block of dense corpus
+    rows, put on the device flat as the file lays them out: ``n`` features,
+    then the label.  A flat put carries no lane padding of the ``n + 1``
+    wide rows; the split into X and y happens here, on the device."""
+    rows = block.reshape(-1, n + 1)
+    z = jnp.dot(rows[:, :n], w, precision=jax.lax.Precision.HIGHEST)
+    return total + problem.sum_margin_loss(z, rows[:, n])
+
+
+def _warm_dense_objective(problem: ERMProblem, n: int, rows: int,
+                          block_rows: int, w) -> None:
+    """Compile :func:`_block_loss_sum` for every block length a pass over
+    ``rows`` rows puts, against the ``w`` the pass will take."""
+    w = jnp.asarray(w)
+    for r in {min(block_rows, rows), rows % block_rows} - {0}:
+        jax.block_until_ready(_block_loss_sum(
+            problem, n, w, jnp.zeros(r * (n + 1), jnp.float32),
+            jnp.zeros((), jnp.float32)))
+
+
+def _dense_objectives(mm: np.ndarray, block_rows: int,
+                      pairs: Sequence[Tuple[ERMProblem, object]],
+                      tracer: Tracer = NULL_TRACER) -> List[float]:
+    """The full objective of each ``(problem, w)`` pair over a dense corpus
+    (``mm``: rows x (features + 1), the label last), in one streamed pass.
+
+    Each contiguous block of ``block_rows`` rows is put on the device once,
+    as it lies in the file, and scored there for every pair.  The partial
+    sums stay on the device and are read once, at the end of the pass
+    (counter ``objective.host_syncs``).  Before block i + 1 is put, the
+    sums through block i - 1 are waited for: at most two blocks are on the
+    device, and the host reads the next block while the device takes the
+    last one.  A pair's value does not depend on the other pairs."""
+    rows, n = mm.shape[0], mm.shape[1] - 1
+    ws = [jnp.asarray(w) for _, w in pairs]
+    totals = [jnp.zeros((), jnp.float32)] * len(pairs)
+    for lo in range(0, rows, block_rows):
+        block = jnp.asarray(np.asarray(mm[lo:lo + block_rows]).reshape(-1))
+        sums = [_block_loss_sum(p, n, w, block, t)
+                for (p, _), w, t in zip(pairs, ws, totals)]
+        jax.block_until_ready(totals)
+        totals = sums
+    read = jax.device_get([(t, jnp.dot(w, w)) for t, w in zip(totals, ws)])
+    if tracer.enabled:
+        tracer.metrics.counter("objective.host_syncs").inc()
+    return [float(s) / rows + 0.5 * p.reg * float(ww)
+            for (p, _), (s, ww) in zip(pairs, read)]
+
+
 @partial(jax.jit, static_argnames=("rows",))
 def _trim_rows(a: jax.Array, rows: int) -> jax.Array:
     return a[:rows]
@@ -1476,13 +1528,14 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
             return streaming_full_grad(problem, w, _row_chunks(),
                                        data_term_only=data_term_only)
 
+        # the objective pass reads the corpus in blocks of a training
+        # chunk's rows, under the same byte budget
+        block_rows = K * b
+
         def eval_obj(w):
-            total = 0.0
-            for Xc, yc in _row_chunks():
-                total += float(problem.data_objective(
-                    w, jnp.asarray(Xc), jnp.asarray(yc))) * Xc.shape[0]
-            return (total / plan_.rows
-                    + 0.5 * problem.reg * float(jnp.dot(w, w)))
+            (obj,) = _dense_objectives(mm, block_rows, [(problem, w)],
+                                       tracer)
+            return obj
 
         def block_losses(w):
             # per-BLOCK mean loss in one streamed pass (blocks = the b-row
@@ -1504,7 +1557,7 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
 
         # what one dense objective pass reads from the memmap
         objective_args = {"rows": plan_.rows,
-                          "chunks": -(-plan_.rows // _EVAL_CHUNK),
+                          "chunks": -(-plan_.rows // block_rows),
                           "bytes": plan_.rows * mm.shape[1] * mm.itemsize}
 
     sharded = plan_.shards > 1
@@ -1553,6 +1606,9 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
                 # lint: allow[REPRO002] warmup placement
                 dummy = jax.device_put(dummy, rep)
             jax.block_until_ready(epoch_fn(dummy, *stage_zeros(k)))
+        if plan_.fmt != CSR:
+            _warm_dense_objective(problem, n, plan_.rows, block_rows,
+                                  host_w(state.w))
         if cfg.solver in ("svrg", "saag2"):
             # the snapshot full-grad stream compiles too — keep it out of
             # epoch 1

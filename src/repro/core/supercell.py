@@ -71,9 +71,9 @@ from ..obs import ACCESS, COMPUTE, EPOCH, GATHER as GATHER_LANE, H2D, \
 from .erm import ERMProblem
 from .experiment import (ARRAYS, CSR, FUSED, RESIDENT, ExecutionPlan,
                          RunResult, _EVAL_CHUNK, _RunCheckpointer,
-                         _objective_jit, _plan_diff, _plan_fingerprint,
-                         _put_blocking, _resume_state, _validate_fingerprint,
-                         execute)
+                         _dense_objectives, _objective_jit, _plan_diff,
+                         _plan_fingerprint, _put_blocking, _resume_state,
+                         _validate_fingerprint, execute)
 from .solvers import (SolverConfig, SolverState, epoch_begin, init_state,
                       make_epoch_fn, make_resident_epoch_fn,
                       make_supercell_epoch_fn, make_supercell_resident_fn,
@@ -475,21 +475,11 @@ def _supercell_streamed(plans: List[ExecutionPlan],
                                        data_term_only=data_term_only)
 
         def eval_cells(ws):
-            # ONE corpus pass evaluates every recording cell: per-chunk
-            # accumulation in solo order, so each value is bit-identical
-            # to the solo eval_obj — only the reads are shared
-            totals = [0.0] * len(ws)
-            for Xc, yc in _row_chunks():
-                Xj, yj = jnp.asarray(Xc), jnp.asarray(yc)
-                for t, (i, w) in enumerate(ws):
-                    totals[t] += float(plans[i].spec.problem.data_objective(
-                        w, Xj, yj)) * Xc.shape[0]
-            out = []
-            for t, (i, w) in enumerate(ws):
-                problem = plans[i].spec.problem
-                out.append(totals[t] / ref.rows
-                           + 0.5 * problem.reg * float(jnp.dot(w, w)))
-            return out
+            # ONE corpus pass evaluates every recording cell, on the solo
+            # driver's blocks and block program, so each value is
+            # bit-identical to the solo run's — only the reads are shared
+            return _dense_objectives(
+                mm, K * b, [(plans[i].spec.problem, w) for i, w in ws])
 
     # compile every lane against every chunk shape, outside the timers
     shapes = sorted({K, m % K} - {0})

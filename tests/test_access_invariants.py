@@ -265,8 +265,10 @@ def test_traced_job_names_every_phase_of_execute(big_dense_corpus,
         (release,) = _spans(res, DRIVER, "release")
         assert release.args["bytes"] == 2 * nbytes
     else:
+        # the pass reads blocks of one training chunk's rows
+        block_rows = res.plan.chunk * spec.batch_size
         assert all(e.args == {"epoch": e.args["epoch"], "rows": BIG_ROWS,
-                              "chunks": -(-BIG_ROWS // 8192),
+                              "chunks": -(-BIG_ROWS // block_rows),
                               "bytes": nbytes} for e in objective)
         waits = _spans(res, WAIT, "chunk")
         counters = res.timeline.metrics["counters"]

@@ -292,6 +292,35 @@ def test_streamed_and_resident_agree_on_cyclic(dense_corpus):
     assert abs(r_s.objective - r_r.objective) < 1e-5
 
 
+@pytest.mark.parametrize("chunk,blocks", [(7, 4), (None, 1)],
+                         ids=["short-last-block", "one-block"])
+def test_streamed_objective_pass_matches_float64(tmp_path, chunk, blocks):
+    """The dense streamed objective pass reads blocks of a training chunk's
+    rows (2,550 rows: 3 x 700 + 450 with chunk 7; one block of 2,550 when
+    the chunk is the whole epoch), and reads the device once per pass.
+    Each history entry is the float64 objective of that epoch's w."""
+    from repro.obs import DRIVER, TracePolicy
+    rows = 2550
+    path = tmp_path / "dense.bin"
+    dataset.synth_erm_corpus(path, rows=rows, features=FEATS, seed=5)
+    mm, _ = dataset.open_corpus(path)
+    X, y = mm[:, :FEATS].astype(np.float64), mm[:, FEATS].astype(np.float64)
+    p = plan(_spec(DataSource.corpus(path), placement=STREAMED, chunk=chunk,
+                   trace=TracePolicy()))
+    res = None
+    for epoch in range(3):
+        res = execute(p, resume=res, epochs=1)
+        w = res.w.astype(np.float64)
+        want = (np.mean(np.logaddexp(0.0, -y * (X @ w)))
+                + 0.5 * p.spec.problem.reg * w @ w)
+        np.testing.assert_allclose(res.history[-1], want, rtol=1e-5)
+        assert len(res.history) == epoch + 1
+        (span,) = [e for e in res.timeline.events
+                   if e.lane == DRIVER and e.name == "objective"]
+        assert span.args["chunks"] == blocks
+        assert res.timeline.metrics["counters"]["objective.host_syncs"] == 1
+
+
 def test_history_trace_is_recorded(arrays):
     X, y = arrays
     res = execute(plan(_spec(DataSource.arrays(X, y), epochs=4)))
