@@ -58,7 +58,8 @@ from ..obs import (ACCESS, COMPUTE, DRIVER, EPOCH, GATHER as GATHER_LANE,
 from . import samplers, schemes
 from .erm import ERMProblem, LOGISTIC, SMOOTH_HINGE, SQUARE
 from .solvers import (CONSTANT, LINE_SEARCH, SOLVERS, SolverConfig,
-                      SolverState, epoch_begin, init_state, make_epoch_fn,
+                      SolverState, batch_access, epoch_begin,
+                      fused_row_dmas, init_state, make_epoch_fn,
                       make_resident_epoch_fn, streaming_full_grad)
 from .step_rules import LS_MODES, VECTORIZED, validate_ls
 
@@ -1372,6 +1373,15 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
                 epoch_fn(dummy, X, y, jax.random.PRNGKey(1)).w)
             jax.block_until_ready(obj(state.w))
 
+    # which fused kernel the epochs run, and the row DMAs an RS epoch's
+    # fused_grad_rows calls issue (counters fused.row_dmas / _bytes)
+    kernel_attr, row_dmas, row_dma_bytes = {}, 0, 0
+    if plan_.kernel == FUSED:
+        kernel_attr = {"kernel": batch_access(plan_.scheme_name)}
+        if kernel_attr["kernel"] == "rows":
+            row_dmas, row_dma_bytes = fused_row_dmas(
+                cfg, plan_.num_batches, spec.batch_size, plan_.features)
+
     # the epoch key schedule is pure in (seed, epoch index): replaying the
     # splits makes a resumed run use the batch schedule the uninterrupted
     # run would have used
@@ -1396,10 +1406,15 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
             with tracer.span("epoch", EPOCH, epoch=done0 + e):
                 with tracer.timespan("resident_epoch", COMPUTE,
                                      epoch=done0 + e,
-                                     step_rule=plan_.step_rule) as sp:
+                                     step_rule=plan_.step_rule,
+                                     **kernel_attr) as sp:
                     state = epoch_fn(state, X, y, sub)
                     jax.block_until_ready(state.w)
             dt = sp.dur
+            if row_dmas:
+                tracer.metrics.counter("fused.row_dmas").inc(row_dmas)
+                tracer.metrics.counter("fused.row_dma_bytes").inc(
+                    row_dma_bytes)
             compute_s += dt
             train_s += dt
             if cfg.step_mode == LINE_SEARCH:

@@ -87,6 +87,27 @@ def _needs_snapshot(solver: str) -> bool:
     return solver in (SVRG, SAAG2)
 
 
+def batch_access(scheme: str) -> str:
+    """How a resident epoch of ``scheme`` reads its batches: ``"block"``,
+    one contiguous block per batch (CS/SS; the fused ``fused_grad_block``),
+    or ``"rows"``, one read per sampled row (RS; ``fused_grad_rows``)."""
+    return "block" if scheme in (samplers.CYCLIC,
+                                 samplers.SYSTEMATIC) else "rows"
+
+
+def fused_row_dmas(cfg: SolverConfig, num_batches: int, batch_size: int,
+                   n: int) -> Tuple[int, int]:
+    """(row DMAs, bytes) of one resident RS epoch's ``fused_grad_rows``
+    calls in :func:`fused_batch_step`: a grid of ``batch_size`` steps per
+    call, one call per batch and a second at the snapshot for
+    SVRG/SAAG-II, each step one aligned row group.  Line-search margin
+    sweeps are not counted."""
+    from ..kernels import fused_erm  # deferred: keep core import pallas-free
+    calls = 2 if _needs_snapshot(cfg.solver) else 1
+    dmas = num_batches * batch_size * calls
+    return dmas, dmas * fused_erm.row_group_bytes(n)
+
+
 def init_state(solver: str, w0: jax.Array, num_batches: int) -> SolverState:
     n = w0.shape[0]
     dt = w0.dtype
@@ -293,7 +314,7 @@ def _run_one_epoch(problem: ERMProblem, cfg: SolverConfig, scheme: str,
             fg = lambda w: problem.full_grad(w, X, y)
         state = epoch_begin(problem, cfg, state, fg)
 
-    contiguous = scheme in (samplers.CYCLIC, samplers.SYSTEMATIC)
+    contiguous = batch_access(scheme) == "block"
     if contiguous:
         starts = samplers.batch_slice_starts(scheme, key, l, batch_size)
         if padded:

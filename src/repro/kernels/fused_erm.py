@@ -60,7 +60,7 @@ compiles them for a described v5e chip.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -125,6 +125,18 @@ def kernel_rows(rows: int, batch_size: int) -> int:
     """Row count the kernels read without padding: ``rows`` itself when it
     is a multiple of 8 and holds one block window, else larger."""
     return max(_round_up(rows, _SUBLANES), _window(batch_size))
+
+
+def _row_group(n: int) -> Tuple[int, int]:
+    """The block one grid step of the rows kernels DMAs: the aligned 8-row
+    group that holds the sampled row."""
+    return (_SUBLANES, n)
+
+
+def row_group_bytes(n: int) -> int:
+    """HBM bytes of one :func:`_row_group` DMA, lanes padded to 128."""
+    rows, cols = _row_group(n)
+    return rows * _round_up(cols, _LANES) * 4
 
 
 def _pad_rows(X: jax.Array, y: jax.Array, batch_size: int):
@@ -278,7 +290,7 @@ def _rows_grid_spec(idx, n: int, out_block, extra_in=()):
         num_scalar_prefetch=1,
         grid=(idx.shape[0],),
         in_specs=[pl.BlockSpec(
-                      (_SUBLANES, n),
+                      _row_group(n),
                       lambda i, idx_ref: (idx_ref[i] // _SUBLANES, 0)),
                   pl.BlockSpec((1, n), lambda i, idx_ref: (0, 0)),
                   *extra_in],

@@ -15,10 +15,12 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import fused_erm
 
-# (rows, features, batch): the HIGGS shape of chip_smoke.py, and a
-# lane-aligned width that splits into feature tiles
+# (rows, features, batch): the HIGGS shape of chip_smoke.py, a
+# lane-aligned width that splits into feature tiles, and the epsilon shape
+# of the benchmark (one tile of 2,000 lanes, padded to 2,048 in HBM)
 SHAPES = {"higgs-n28": (11_000_000, 28, 1000),
-          "wide-n2048": (65_536, 2048, 1000)}
+          "wide-n2048": (65_536, 2048, 1000),
+          "epsilon-n2000": (400_000, 2000, 1000)}
 
 
 @pytest.fixture(scope="module")
