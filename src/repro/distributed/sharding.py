@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import re
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -361,6 +362,16 @@ def staging_shardings(mesh: Mesh, batch_axes: Sequence[Sequence[Logical]],
         for ax, shp in zip(batch_axes, shapes))
 
 
+@functools.lru_cache(maxsize=None)
+def _replicate(mesh: Mesh):
+    """The compiled reshard to fully replicated on ``mesh``: an identity
+    program whose outputs are replicated, which XLA lowers to all-gathers
+    over the interconnect, so the bytes never leave the devices.  Cached
+    per mesh: every put built on one mesh (a job's warm-up and its live
+    stager, and every later job) shares one compile cache."""
+    return jax.jit(lambda *xs: xs, out_shardings=NamedSharding(mesh, P()))
+
+
 def make_staging_put(mesh: Mesh, batch_axes: Sequence[Sequence[Logical]],
                      gather: bool = False, stats=None, tracer=None):
     """Build a ``put`` callable for :class:`repro.data.pipeline.DeviceStager`
@@ -369,17 +380,21 @@ def make_staging_put(mesh: Mesh, batch_axes: Sequence[Sequence[Logical]],
     only its ``1/data_parallel_width`` slice over the host->device link.
 
     With ``gather=True`` the staged shards are then resharded to fully
-    replicated (a device-to-device all-gather, still inside the staging
-    thread so it overlaps compute).  This is the ``reduction='gather'``
+    replicated by one compiled program (:func:`_replicate`: all-gathers
+    over the interconnect, device to device), still inside the staging
+    thread so it overlaps compute.  This is the ``reduction='gather'``
     staging mode: per-device H2D traffic drops by the mesh width while the
     consuming jit sees replicated inputs — bit-identical arithmetic to the
-    single-host engines.  The gather time is recorded separately on
+    single-host engines.  An array that is already replicated (a batch dim
+    that does not divide the mesh) passes through unchanged.  The gather
+    time, up to the all-gathers' completion, is recorded separately on
     ``stats`` (an :class:`~repro.data.pipeline.AccessStats`) so the H2D
-    column keeps measuring the host link only."""
+    column keeps measuring the host link only; an enabled ``tracer``
+    counts the resharded arrays (``gather.reshards``) and their bytes
+    (``gather.bytes``)."""
     from ..obs import GATHER, NULL_TRACER
 
     tracer = tracer if tracer is not None else NULL_TRACER
-    replicated = NamedSharding(mesh, P())
 
     def put(host):
         shardings = staging_shardings(
@@ -393,10 +408,13 @@ def make_staging_put(mesh: Mesh, batch_axes: Sequence[Sequence[Logical]],
             # gather lane and gather_s cannot drift (they used to be two
             # separate perf_counter pairs waiting to diverge)
             with tracer.timespan("reshard", GATHER) as sp:
-                dev = jax.block_until_ready(tuple(
-                    jax.device_put(a, replicated) for a in dev))
+                dev = jax.block_until_ready(_replicate(mesh)(*dev))
             if stats is not None:
                 stats.record_gather(sp.dur)
+            if tracer.enabled:
+                tracer.metrics.counter("gather.reshards").inc(len(dev))
+                tracer.metrics.counter("gather.bytes").inc(
+                    sum(a.nbytes for a in dev))
         return dev
 
     return put
