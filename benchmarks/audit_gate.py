@@ -61,12 +61,6 @@ def _cells(dense, csr, mesh):
         "sparse-csr": spec(DataSource.corpus(csr), solver="saga", chunk=4),
         "resident-eager": spec(DataSource.corpus(dense), solver="sag"),
         "resident-fused": spec(DataSource.corpus(dense), kernel="fused"),
-        # the vmapped super-cell chunk engine: solo chunk avals, state
-        # stacked to 4 cells — proves statically that ONE staged payload
-        # drives S cells (audit() lowers it via supercell=4)
-        "supercell-streamed[s=4]": spec(DataSource.corpus(dense),
-                                        solver="saga", placement=STREAMED,
-                                        chunk=4),
         # the importance-weighted adaptive engines (PR 10): the extra
         # (k,) weight aval rides the chunk payload and the batch dim is a
         # BOUND — padded buffers must still reconcile H2D bytes exactly
@@ -138,8 +132,7 @@ def main(argv=None) -> int:
                                            density=0.05, seed=5)
         reports = {}
         for name, spec in _cells(dense, csr, mesh).items():
-            s_cells = 4 if name.startswith("supercell") else None
-            report = audit(plan(spec), supercell=s_cells)
+            report = audit(plan(spec))
             reports[name] = report.to_json()
             verdict = "ok" if report.ok else "FAIL"
             print(f"audit: {name:28s} backend={report.backend:18s} "

@@ -298,12 +298,9 @@ def main_supercell(rows=100_000, features=64, batch=500, epochs=3, cells=8,
     headline ``access_h2d_amortization`` ratio (expected ~S: the shared
     stream does the same read/convert/H2D work ONCE for S cells) and
     ``trajectory_max_dw`` — the max |w_solo - w_supercell| across cells,
-    exactly 0.0 in the default bit-exact mode (the super-cell contract,
-    see tests/test_supercell.py); a ``vmap_lanes=True`` row, where the S
-    cells additionally share one vmapped engine call per chunk (fastest,
-    but its batched matvecs may drift from solo by ulps — its max_dw
-    column reports the measured drift); and the train-wall comparisons
-    (span-measured epoch time, compile excluded).
+    exactly 0.0 (the super-cell contract, see tests/test_supercell.py);
+    and the train-wall comparison (span-measured epoch time, compile
+    excluded).
     """
     import numpy as np
 
@@ -322,25 +319,17 @@ def main_supercell(rows=100_000, features=64, batch=500, epochs=3, cells=8,
     plans = [plan(s) for s in specs]
     solos = [execute(p) for p in plans]
     supers = execute_supercell(plans)
-    vmapped = execute_supercell(plans, vmap_lanes=True)
 
     mean = lambda xs: sum(xs) / len(xs)                      # noqa: E731
     ah = lambda b: b["access_s_per_epoch"] + b["h2d_s_per_epoch"]  # noqa: E731
     solo_b = [r.breakdown() for r in solos]
     sup_b = [r.breakdown() for r in supers]
-    vm_b = [r.breakdown() for r in vmapped]
     solo_ah, sup_ah = mean([ah(b) for b in solo_b]), mean([ah(b) for b in sup_b])
-    vm_ah = mean([ah(b) for b in vm_b])
-
-    def _max_dw(refs, others):
-        return max(float(np.max(np.abs(s.w - c.w)))
-                   for s, c in zip(refs, others))
 
     # train_s sums are span-measured epoch walls (compile/warmup excluded);
     # the supercell's per-cell train_s is wall/S, so the sum IS its wall
     solo_wall = sum(r.train_s for r in solos)
     super_wall = sum(r.train_s for r in supers)
-    vm_wall = sum(r.train_s for r in vmapped)
 
     def _row(tag, rs, bs, n_cells):
         return {"name": f"supercell_{tag}_{solver}_{scheme}",
@@ -359,21 +348,15 @@ def main_supercell(rows=100_000, features=64, batch=500, epochs=3, cells=8,
     r_sup = _row(f"s{cells}", supers, sup_b, cells)
     r_sup["access_h2d_amortization"] = (solo_ah / sup_ah
                                         if sup_ah > 0 else float("inf"))
-    r_sup["trajectory_max_dw"] = _max_dw(solos, supers)
-    r_vm = _row(f"s{cells}_vmapped", vmapped, vm_b, cells)
-    r_vm["access_h2d_amortization"] = (solo_ah / vm_ah
-                                       if vm_ah > 0 else float("inf"))
-    r_vm["trajectory_max_dw"] = _max_dw(solos, vmapped)
+    r_sup["trajectory_max_dw"] = max(float(np.max(np.abs(s.w - c.w)))
+                                     for s, c in zip(solos, supers))
     r_wall = {"name": f"supercell_wall_{solver}_{scheme}",
               "solver": solver, "scheme": scheme, "cells": cells,
               "epochs": epochs, "solo_train_wall_s": solo_wall,
               "supercell_train_wall_s": super_wall,
-              "vmapped_train_wall_s": vm_wall,
               "wall_speedup": (solo_wall / super_wall
-                               if super_wall > 0 else float("inf")),
-              "vmapped_wall_speedup": (solo_wall / vm_wall
-                                       if vm_wall > 0 else float("inf"))}
-    results = [r_solo, r_sup, r_vm, r_wall]
+                               if super_wall > 0 else float("inf"))}
+    results = [r_solo, r_sup, r_wall]
     if json_out:
         payload = {"meta": {"schema": 1, "supercell": True, "rows": rows,
                             "features": features, "batch": batch,
@@ -384,7 +367,7 @@ def main_supercell(rows=100_000, features=64, batch=500, epochs=3, cells=8,
                    "results": results}
         Path(json_out).write_text(json.dumps(payload, indent=2) + "\n")
     out = []
-    for r in (r_solo, r_sup, r_vm):
+    for r in (r_solo, r_sup):
         d = (f"objective={r['objective']:.10f};"
              f"access_ms={r['access_s_per_epoch']*1e3:.3f};"
              f"h2d_ms={r['h2d_s_per_epoch']*1e3:.3f};"
@@ -397,9 +380,7 @@ def main_supercell(rows=100_000, features=64, batch=500, epochs=3, cells=8,
     out.append((r_wall["name"], super_wall * 1e6,
                 f"solo_wall_s={solo_wall:.3f};"
                 f"supercell_wall_s={super_wall:.3f};"
-                f"vmapped_wall_s={vm_wall:.3f};"
-                f"wall_speedup={r_wall['wall_speedup']:.2f};"
-                f"vmapped_wall_speedup={r_wall['vmapped_wall_speedup']:.2f}"))
+                f"wall_speedup={r_wall['wall_speedup']:.2f}"))
     return out
 
 

@@ -43,7 +43,7 @@ import hashlib
 import json
 import re
 from collections import Counter
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,8 +53,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..core import experiment as expmod
 from ..core.experiment import (CSR, GATHER, PSUM, ExecutionPlan,
                                ExperimentSpec, PlanError)
-from ..core.solvers import (init_state, make_epoch_fn,
-                            make_resident_epoch_fn, make_supercell_epoch_fn)
+from ..core.solvers import init_state, make_epoch_fn, make_resident_epoch_fn
 from ..distributed.sharding import staging_shardings
 from ..launch.hlo_cost import HloCostModel, _type_bytes
 from ..launch.hlo_analysis import COLLECTIVES, memory_dict
@@ -328,70 +327,7 @@ def _resident_unit(plan_: ExecutionPlan) -> List[_Unit]:
                         _per_device_bytes(y, mesh)])]
 
 
-def _supercell_units(plan_: ExecutionPlan, s_cells: int) -> List[_Unit]:
-    """Lowering units for the vmapped super-cell chunk engine: the SAME
-    staged chunk avals as the solo streamed unit (data bytes shared — NOT
-    multiplied by S), the solver state stacked to S× leaves, plus the
-    per-cell ``step0S`` scalar vector.  The h2d rule then proves the
-    amortization claim statically: entry parameters show one chunk payload
-    driving S cells' state."""
-    from ..core.supercell import supercell_key
-    if s_cells < 2:
-        raise PlanError(f"supercell audit wants >= 2 cells (got {s_cells})")
-    if supercell_key(plan_) is None:
-        raise PlanError(
-            "plan is not super-cell eligible (sharded or fused backend)")
-    if plan_.placement != expmod.STREAMED:
-        raise PlanError(
-            "the super-cell audit lowers the chunked engine: use a "
-            "streamed plan (the resident super-cell body is traced per "
-            "call, not a cacheable jit)")
-    spec, cfg = plan_.spec, plan_.cfg
-    m, n, b = plan_.num_batches, plan_.features, spec.batch_size
-    K = plan_.chunk
-    # lane-normalized cfg, exactly like the driver: step size rides step0S
-    lane_cfg = cfg._replace(step_size=1.0)
-    fn = make_supercell_epoch_fn(spec.problem, lane_cfg)
-    state = _state_avals(plan_)
-    stateS = jax.tree.map(
-        lambda l: jax.ShapeDtypeStruct((s_cells,) + l.shape, l.dtype), state)
-    step0S = _sds((s_cells,), jnp.float32)
-    units: List[_Unit] = []
-    for k in sorted({K, m % K} - {0}):
-        if plan_.fmt == CSR:
-            shapes = [(k, b, plan_.kmax), (k, b, plan_.kmax), (k, b), (k,)]
-            dtypes = [jnp.int32, jnp.float32, jnp.float32, jnp.int32]
-        else:
-            shapes = [(k, b, n), (k, b), (k,)]
-            dtypes = [jnp.float32, jnp.float32, jnp.int32]
-        data = tuple(_sds(s, d) for s, d in zip(shapes, dtypes))
-        data_global = sum(_aval_bytes(a) for a in data)
-
-        def lower(epoch: int, fn=fn, data=data):
-            del epoch   # shapes are epoch-invariant by construction
-            return fn.lower(stateS, *data, step0S)
-
-        units.append(_Unit(
-            name=f"supercell_chunk[k={k},cells={s_cells}]", lower=lower,
-            scan_trips=k,
-            state_leaf_bytes=_leaf_bytes(stateS),
-            data_bytes_global=data_global,
-            data_bytes_per_device=data_global,
-            # ONE staged chunk serves all S cells — the byte model the
-            # runtime attributes at shared/S per cell
-            model_h2d_bytes=data_global, pad_bytes=0, donated=True,
-            # step0S enters as an extra entry param the stager never
-            # books (device_put once per segment) — model it like the
-            # resident key param
-            key_bytes=_aval_bytes(step0S),
-            data_arg_bytes=[_aval_bytes(a) for a in data]))
-    return units
-
-
-def _build_units(plan_: ExecutionPlan,
-                 supercell: Optional[int] = None) -> List[_Unit]:
-    if supercell is not None:
-        return _supercell_units(plan_, supercell)
+def _build_units(plan_: ExecutionPlan) -> List[_Unit]:
     if plan_.placement == expmod.RESIDENT:
         return _resident_unit(plan_)
     return _streamed_units(plan_)
@@ -619,19 +555,12 @@ _RULE_FNS = {
 # entry points
 # ---------------------------------------------------------------------------
 
-def audit(spec_or_plan, *, supercell: Optional[int] = None) -> AuditReport:
+def audit(spec_or_plan) -> AuditReport:
     """Statically verify a spec/plan's access contract — zero execution.
 
     Accepts an :class:`ExperimentSpec` (planned first) or an
     :class:`ExecutionPlan`; returns an :class:`AuditReport` with one
     pass/fail/skip :class:`RuleResult` per rule per lowered unit.
-
-    ``supercell=S`` audits the vmapped super-cell chunk engine instead of
-    the plan's solo engines: the state avals are stacked to a leading
-    cell axis of S while the staged chunk avals stay the SOLO shapes —
-    the lowered entry parameters then prove statically that one chunk
-    payload drives S cells (the amortization the runtime attributes at
-    ``shared / S`` per cell).
     """
     if isinstance(spec_or_plan, ExecutionPlan):
         plan_ = spec_or_plan
@@ -646,7 +575,7 @@ def audit(spec_or_plan, *, supercell: Optional[int] = None) -> AuditReport:
             f"plan wants {plan_.shards} devices but only "
             f"{jax.device_count()} are visible — sharded plans lower "
             f"against their mesh (CI forces CPU devices via XLA_FLAGS)")
-    units = _build_units(plan_, supercell)
+    units = _build_units(plan_)
     audits = []
     for unit in units:
         an = _analyze_unit(unit)

@@ -1075,7 +1075,9 @@ def execute(plan_: ExecutionPlan, *, resume: Optional[RunResult] = None,
 
     ``resume`` continues from a previous result OF THE SAME PLAN: the solver
     state is copied (the stored result stays usable) and the sampler resumes
-    at the exact step an uninterrupted run would be at.
+    at the exact step an uninterrupted run would be at.  A solo run is the
+    one-cell case of the placement's driver, which
+    :func:`~repro.core.supercell.execute_supercell` runs over S cells.
     """
     # first, so the timeline's origin is the call's start: every second of
     # the call lies on the tracer's clock
@@ -1083,51 +1085,58 @@ def execute(plan_: ExecutionPlan, *, resume: Optional[RunResult] = None,
               if plan_.spec.trace is not None else NULL_TRACER)
     epochs = plan_.spec.epochs if epochs is None else epochs
     if resume is not None:
-        if resume.solver_state is None:
-            raise ValueError(
-                "resume result carries no solver state (RunResult.from_json "
-                "rebuilds the summary surface only) — reconstruct resumable "
-                "state from an on-disk checkpoint via "
-                "repro.api.resume_from(directory)")
-        prev, cur = resume.plan.spec.data, plan_.spec.data
-        # DataSource equality deliberately excludes array payloads (specs
-        # stay hashable), so in-memory sources additionally require the
-        # SAME arrays — resuming SAG/SAGA gradient memory against other
-        # data would silently corrupt the run
-        same_arrays = (prev.kind != ARRAYS
-                       or (prev.X is cur.X and prev.y is cur.y))
-        # identity is the RESOLVED trajectory (fingerprint + psum rule),
-        # not raw spec equality: a plan rebuilt from a checkpoint's
-        # fingerprint forces fields the original spec left 'auto', and the
-        # elastic fields (mesh width, chunking, epoch budget) may change
-        # across a restart
-        try:
-            _validate_fingerprint(_plan_fingerprint(resume.plan), plan_)
-            same_run = True
-        except ValueError:
-            same_run = False
-        if not same_run or not same_arrays:
-            diffs = _plan_diff(resume.plan, plan_)
-            if not same_arrays:
-                diffs.append("spec.data: in-memory sources must be the "
-                             "same arrays (X/y object identity)")
-            raise ValueError(
-                "resume result came from a different plan than the one "
-                "being executed — a resumed run must continue the SAME "
-                "plan (and, for in-memory sources, the same arrays) or the "
-                "batch schedule silently diverges from an uninterrupted "
-                "run; differing fields:\n  "
-                + "\n  ".join(diffs
-                              or ["(plans compare unequal with no "
-                                  "field-level difference)"]))
+        _validate_resume(plan_, resume)
     with _compile_events(tracer):
-        if plan_.placement == RESIDENT:
-            result = _execute_resident(plan_, resume, epochs, tracer)
-        else:
-            result = _execute_streamed(plan_, resume, epochs, tracer)
+        [result] = _drive([plan_], [resume], epochs, tracer, [tracer])
+    return _attach_timeline(plan_, tracer, result)
+
+
+def _validate_resume(plan_: ExecutionPlan, resume: RunResult) -> None:
+    """Raise unless ``resume`` can continue ``plan_``."""
+    if resume.solver_state is None:
+        raise ValueError(
+            "resume result carries no solver state (RunResult.from_json "
+            "rebuilds the summary surface only) — reconstruct resumable "
+            "state from an on-disk checkpoint via "
+            "repro.api.resume_from(directory)")
+    prev, cur = resume.plan.spec.data, plan_.spec.data
+    # DataSource equality deliberately excludes array payloads (specs
+    # stay hashable), so in-memory sources additionally require the
+    # SAME arrays — resuming SAG/SAGA gradient memory against other
+    # data would silently corrupt the run
+    same_arrays = (prev.kind != ARRAYS
+                   or (prev.X is cur.X and prev.y is cur.y))
+    # identity is the RESOLVED trajectory (fingerprint + psum rule),
+    # not raw spec equality: a plan rebuilt from a checkpoint's
+    # fingerprint forces fields the original spec left 'auto', and the
+    # elastic fields (mesh width, chunking, epoch budget) may change
+    # across a restart
+    try:
+        _validate_fingerprint(_plan_fingerprint(resume.plan), plan_)
+        same_run = True
+    except ValueError:
+        same_run = False
+    if not same_run or not same_arrays:
+        diffs = _plan_diff(resume.plan, plan_)
+        if not same_arrays:
+            diffs.append("spec.data: in-memory sources must be the "
+                         "same arrays (X/y object identity)")
+        raise ValueError(
+            "resume result came from a different plan than the one "
+            "being executed — a resumed run must continue the SAME "
+            "plan (and, for in-memory sources, the same arrays) or the "
+            "batch schedule silently diverges from an uninterrupted "
+            "run; differing fields:\n  "
+            + "\n  ".join(diffs
+                          or ["(plans compare unequal with no "
+                              "field-level difference)"]))
+
+
+def _attach_timeline(plan_: ExecutionPlan, tracer: Tracer,
+                     result: RunResult) -> RunResult:
     if tracer.enabled:
         # the timeline is PER-CALL, like stats: each segment of a resumed
-        # run carries (and, below, writes) its own trace
+        # run carries (and writes) its own trace
         result.timeline = tracer.timeline()
         if plan_.spec.trace.path is not None:
             result.timeline.save(plan_.spec.trace.path)
@@ -1151,6 +1160,37 @@ def _compile_events(tracer: Tracer):
 def run_experiment(spec: ExperimentSpec) -> RunResult:
     """``execute(plan(spec))`` — the one-call path."""
     return execute(plan(spec))
+
+
+def _drive(plans: Sequence[ExecutionPlan],
+           resumes: Sequence[Optional[RunResult]], epochs: int,
+           tracer: Tracer, tracers: Sequence[Tracer]) -> List[RunResult]:
+    """Run S >= 1 cells that share one data plan (``plans[0]`` fixes it):
+    the data side runs once, on ``tracer``; each cell's solver runs on the
+    shared device data, its compute spans and counters on its own entry of
+    ``tracers``.  A solo run passes its own tracer as both."""
+    drive = (_drive_resident if plans[0].placement == RESIDENT
+             else _drive_streamed)
+    return drive(list(plans), list(resumes), epochs, tracer, list(tracers))
+
+
+def _cell_stats(shared, cells: int):
+    """The shared stream's :class:`AccessStats`, attributed to one of
+    ``cells`` cells: time and bytes divide by the cell count (one read
+    served every cell), batch/stage counts stay — ``s_per_batch`` then
+    reads as the AMORTIZED per-batch access time, which is the quantity
+    the paper's cost model multiplies by ``m``."""
+    from ..data import pipeline as pipemod
+    return pipemod.AccessStats(
+        batches=shared.batches,
+        access_s=shared.access_s / cells,
+        bytes_read=shared.bytes_read // cells,
+        staged=shared.staged,
+        h2d_s=shared.h2d_s / cells,
+        bytes_staged=shared.bytes_staged // cells,
+        h2d_saved_s=shared.h2d_saved_s / cells,
+        shards=shared.shards,
+        gather_s=shared.gather_s / cells)
 
 
 def _resume_state(plan_: ExecutionPlan, resume: Optional[RunResult],
@@ -1279,13 +1319,19 @@ def _stage_resident_sharded(plan_: ExecutionPlan, Xh: np.ndarray,
     return X, y, h2d_dt
 
 
-def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
-                      epochs: int, tracer: Tracer = NULL_TRACER) -> RunResult:
+def _drive_resident(plans: List[ExecutionPlan],
+                    resumes: List[Optional[RunResult]], epochs: int,
+                    tracer: Tracer, tracers: List[Tracer]) -> List[RunResult]:
+    """The resident engine: read the corpus once, stage it whole on the
+    device (sharded across the mesh when the plan has one), then run each
+    epoch in one device call per cell.  The per-epoch objective and the
+    checkpoints run outside the timers."""
     from ..data import pipeline as pipemod
 
-    spec, cfg = plan_.spec, plan_.cfg
-    problem = spec.problem
-    sharded = plan_.shards > 1
+    ref = plans[0]
+    spec = ref.spec
+    S, m = len(plans), ref.num_batches
+    sharded = ref.shards > 1
     stats = pipemod.AccessStats()
     h2d_dt = 0.0
 
@@ -1295,7 +1341,7 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
                 Xh = np.ascontiguousarray(np.asarray(spec.data.X, np.float32))
                 yh = np.ascontiguousarray(np.asarray(spec.data.y, np.float32))
                 sp.set(bytes=Xh.nbytes + yh.nbytes)
-            X, y, h2d_dt = _stage_resident_sharded(plan_, Xh, yh, stats,
+            X, y, h2d_dt = _stage_resident_sharded(ref, Xh, yh, stats,
                                                    tracer)
         else:
             X = jnp.asarray(spec.data.X, jnp.float32)
@@ -1307,7 +1353,7 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
             tracer=tracer)
         stats = pipe.stats
         rows = pipe.read_all()
-        n = plan_.features
+        n = ref.features
         # contiguity copies BEFORE the timer: device_put of a strided view
         # would hide a host-side memcpy inside the H2D number
         with tracer.span("layout", DRIVER) as sp:
@@ -1315,7 +1361,7 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
             yh = np.ascontiguousarray(rows[:, n])
             sp.set(bytes=Xh.nbytes + yh.nbytes)
         if sharded:
-            X, y, h2d_dt = _stage_resident_sharded(plan_, Xh, yh, stats,
+            X, y, h2d_dt = _stage_resident_sharded(ref, Xh, yh, stats,
                                                    tracer)
         else:
             with tracer.timespan("stage_resident", H2D,
@@ -1336,90 +1382,107 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
     # engine needs the true row count (schedule, clamping, masked snapshot
     # gradients); 'gather' and single-host see an unpadded corpus and run
     # the original program — the bit-parity surface
-    psum = sharded and plan_.reduction == PSUM
-    epoch_fn = make_resident_epoch_fn(problem, cfg, plan_.scheme_name,
-                                      spec.batch_size,
-                                      rows=plan_.rows if psum else None)
-    if psum:
-        obj = lambda w: _masked_objective_jit(problem, plan_.rows, w, X, y)
-    else:
-        obj = lambda w: _objective_jit(problem, w, X, y)
+    psum = sharded and ref.reduction == PSUM
+    fns = [make_resident_epoch_fn(p.spec.problem, p.cfg, ref.scheme_name,
+                                  spec.batch_size,
+                                  rows=ref.rows if psum else None)
+           for p in plans]
+
+    def obj(i, w):
+        problem = plans[i].spec.problem
+        if psum:
+            return _masked_objective_jit(problem, ref.rows, w, X, y)
+        return _objective_jit(problem, w, X, y)
+
     with tracer.span("init", DRIVER):
-        state, done0 = _resume_state(plan_, resume)
+        # nothing else may hold a cell's initial state: the resident
+        # engines do not donate it, so a second reference would keep it
+        # on the device for the whole run
+        states, done0s = map(list, zip(*(_resume_state(p, r)
+                                         for p, r in zip(plans, resumes))))
+        done0 = done0s[0]
         if sharded:
             # solver state rides the mesh replicated: a fresh (or resumed)
             # state on the default device would force jit to re-specialize
             # against the committed corpus shardings
             # lint: allow[REPRO002] state placement
-            state = jax.device_put(
-                state, NamedSharding(spec.mesh, PartitionSpec()))
+            states = [jax.device_put(
+                st, NamedSharding(spec.mesh, PartitionSpec()))
+                for st in states]
 
-    if resume is None:
+    if all(r is None for r in resumes):
         # compile (epoch fn, embedded snapshot refresh, objective) untimed;
         # a resumed call reuses the original call's jit cache, and paying a
         # full warmup epoch per segment would double the device work of
         # epoch-at-a-time drivers like benchmarks/erm_convergence.py
         with tracer.span("warmup", DRIVER):
-            dummy = init_state(cfg.solver,
-                               jnp.zeros(plan_.features, jnp.float32),
-                               plan_.num_batches)
-            if sharded:
-                # match the live state's sharding or the warmup compiles a
-                # throwaway specialization
-                # lint: allow[REPRO002] warmup placement
-                dummy = jax.device_put(
-                    dummy, NamedSharding(spec.mesh, PartitionSpec()))
-            jax.block_until_ready(
-                epoch_fn(dummy, X, y, jax.random.PRNGKey(1)).w)
-            jax.block_until_ready(obj(state.w))
+            for i, p in enumerate(plans):
+                dummy = init_state(p.cfg.solver,
+                                   jnp.zeros(ref.features, jnp.float32), m)
+                if sharded:
+                    # match the live state's sharding or the warmup
+                    # compiles a throwaway specialization
+                    # lint: allow[REPRO002] warmup placement
+                    dummy = jax.device_put(
+                        dummy, NamedSharding(spec.mesh, PartitionSpec()))
+                jax.block_until_ready(
+                    fns[i](dummy, X, y, jax.random.PRNGKey(1)).w)
+                jax.block_until_ready(obj(i, states[i].w))
 
     # which fused kernel the epochs run, and the row DMAs an RS epoch's
-    # fused_grad_rows calls issue (counters fused.row_dmas / _bytes)
+    # fused_grad_rows calls issue (counters fused.row_dmas / _bytes); fused
+    # plans never share a corpus, so ref is the only cell
     kernel_attr, row_dmas, row_dma_bytes = {}, 0, 0
-    if plan_.kernel == FUSED:
-        kernel_attr = {"kernel": batch_access(plan_.scheme_name)}
+    if ref.kernel == FUSED:
+        kernel_attr = {"kernel": batch_access(ref.scheme_name)}
         if kernel_attr["kernel"] == "rows":
             row_dmas, row_dma_bytes = fused_row_dmas(
-                cfg, plan_.num_batches, spec.batch_size, plan_.features)
+                ref.cfg, m, spec.batch_size, ref.features)
 
     # the epoch key schedule is pure in (seed, epoch index): replaying the
     # splits makes a resumed run use the batch schedule the uninterrupted
-    # run would have used
+    # run would have used; every cell shares it (same seed)
     key = jax.random.PRNGKey(spec.seed)
     for _ in range(done0):
         key, _ = jax.random.split(key)
 
+    def objective(i, epoch):
+        with tracer.span("objective", DRIVER, epoch=epoch):
+            return float(obj(i, states[i].w))
+
     # the trace is cumulative across resumes: prepending the resumed-from
     # history makes any chain of segments read like one uninterrupted run
-    prefix = [] if resume is None else [float(h) for h in resume.history]
-    history: List[float] = []
-    compute_s = 0.0
+    prefixes = [[] if r is None else [float(h) for h in r.history]
+                for r in resumes]
+    histories: List[List[float]] = [[] for _ in plans]
+    compute_s = [0.0] * S
     train_s = 0.0
-    rck = _RunCheckpointer(plan_, done0, epochs, tracer)
+    rcks = [_RunCheckpointer(p, done0, epochs, t)
+            for p, t in zip(plans, tracers)]
     try:
         for e in range(epochs):
             key, sub = jax.random.split(key)
-            # the whole epoch is ONE device call here, so the compute span
-            # is the epoch; VectorizedLS trial ladders run fused inside the
+            # each cell's epoch is ONE device call, so its compute span is
+            # its epoch; VectorizedLS trial ladders run fused inside the
             # jit, so the span carries the step rule as an attribute and
             # the ladder count lands on the ls.invocations counter below
-            with tracer.span("epoch", EPOCH, epoch=done0 + e):
-                with tracer.timespan("resident_epoch", COMPUTE,
-                                     epoch=done0 + e,
-                                     step_rule=plan_.step_rule,
-                                     **kernel_attr) as sp:
-                    state = epoch_fn(state, X, y, sub)
-                    jax.block_until_ready(state.w)
-            dt = sp.dur
-            if row_dmas:
-                tracer.metrics.counter("fused.row_dmas").inc(row_dmas)
-                tracer.metrics.counter("fused.row_dma_bytes").inc(
-                    row_dma_bytes)
-            compute_s += dt
-            train_s += dt
-            if cfg.step_mode == LINE_SEARCH:
-                tracer.metrics.counter("ls.invocations").inc(
-                    plan_.num_batches)
+            with tracer.timespan("epoch", EPOCH, epoch=done0 + e) as se:
+                for i, p in enumerate(plans):
+                    with tracers[i].timespan("resident_epoch", COMPUTE,
+                                             epoch=done0 + e,
+                                             step_rule=p.step_rule,
+                                             **kernel_attr) as sp:
+                        states[i] = fns[i](states[i], X, y, sub)
+                        jax.block_until_ready(states[i].w)
+                    compute_s[i] += sp.dur
+            train_s += se.dur
+            for t, p in zip(tracers, plans):
+                if row_dmas:
+                    t.metrics.counter("fused.row_dmas").inc(row_dmas)
+                    t.metrics.counter("fused.row_dma_bytes").inc(
+                        row_dma_bytes)
+                if p.cfg.step_mode == LINE_SEARCH:
+                    t.metrics.counter("ls.invocations").inc(m)
             if spec.data.kind != ARRAYS and e > 0:
                 # every epoch after the first of THIS call would have
                 # restaged the corpus (a resumed call pays its own staging,
@@ -1427,67 +1490,77 @@ def _execute_resident(plan_: ExecutionPlan, resume: Optional[RunResult],
                 # keeps split runs' totals consistent with their actual
                 # staging count)
                 stats.record_h2d_saved(h2d_dt)
-            if spec.record_objective:
-                with tracer.span("objective", DRIVER, epoch=done0 + e):
-                    history.append(float(obj(state.w)))
-            rck.after_epoch(e, state,
-                            {"scheme": plan_.scheme_name, "seed": spec.seed,
-                             "epochs": done0 + e + 1},
-                            prefix + history, stats)
+            for i, p in enumerate(plans):
+                if p.spec.record_objective:
+                    histories[i].append(objective(i, done0 + e))
+                rcks[i].after_epoch(
+                    e, states[i], {"scheme": ref.scheme_name,
+                                   "seed": spec.seed,
+                                   "epochs": done0 + e + 1},
+                    prefixes[i] + histories[i], _cell_stats(stats, S))
     finally:
-        rck.finish()
+        for rck in rcks:
+            rck.finish()
 
-    if history:
-        objective = history[-1]
-    else:
-        with tracer.span("objective", DRIVER, epoch=done0 + epochs - 1):
-            objective = float(obj(state.w))
-    return RunResult(
-        plan=plan_, objective=objective,
-        history=np.asarray(prefix + history),
-        w=np.asarray(state.w), solver_state=state,
-        sampler_state={"scheme": plan_.scheme_name, "seed": spec.seed,
+    return [RunResult(
+        plan=p, objective=(histories[i][-1] if histories[i]
+                           else objective(i, done0 + epochs - 1)),
+        history=np.asarray(prefixes[i] + histories[i]),
+        w=np.asarray(states[i].w), solver_state=states[i],
+        sampler_state={"scheme": ref.scheme_name, "seed": spec.seed,
                        "epochs": done0 + epochs},
-        epochs_run=epochs, epochs_done=done0 + epochs, stats=stats,
-        train_s=train_s, compute_s=compute_s)
+        epochs_run=epochs, epochs_done=done0 + epochs,
+        stats=_cell_stats(stats, S), train_s=train_s / S,
+        compute_s=compute_s[i]) for i, p in enumerate(plans)]
 
 
 # ---- streamed backends -----------------------------------------------------
 
-def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
-                      epochs: int, tracer: Tracer = NULL_TRACER) -> RunResult:
+def _drive_streamed(plans: List[ExecutionPlan],
+                    resumes: List[Optional[RunResult]], epochs: int,
+                    tracer: Tracer, tracers: List[Tracer]) -> List[RunResult]:
+    """The streaming engine under the dense and sparse backends: group the
+    pipeline's batch stream into <=K-batch chunks (never crossing an epoch
+    boundary — snapshot solvers refresh state between epochs),
+    double-buffer them host->device (DeviceStager), and scan each chunk in
+    one device call per cell.  The per-epoch objective pass and the
+    checkpoints run outside the timers, at every epoch boundary."""
     from ..data import pipeline as pipemod
 
-    spec, cfg = plan_.spec, plan_.cfg
-    problem = spec.problem
-    m, K, n = plan_.num_batches, plan_.chunk, plan_.features
+    ref = plans[0]
+    spec = ref.spec
+    S = len(plans)
+    m, K, n = ref.num_batches, ref.chunk, ref.features
     b = spec.batch_size
     with tracer.span("init", DRIVER):
-        state, done0 = _resume_state(plan_, resume)
+        states, done0s = map(list, zip(*(_resume_state(p, r)
+                                         for p, r in zip(plans, resumes))))
+    done0 = done0s[0]
     start_step = done0 * m
-    scheme_obj = plan_.scheme_obj
+    scheme_obj = ref.scheme_obj
     adaptive = scheme_obj.adaptive
-    epoch_fn = (make_epoch_fn(problem, cfg, weighted=True) if adaptive
-                else make_epoch_fn(problem, cfg))
+    fns = [make_epoch_fn(p.spec.problem, p.cfg, weighted=True) if adaptive
+           else make_epoch_fn(p.spec.problem, p.cfg) for p in plans]
 
     # adaptive schemes: read-ahead is disabled (prefetch=0) so the sampler
     # state is exact at every epoch boundary — observe() feedback and the
     # checkpointed sampler_meta() must see exactly the consumed draws; a
     # resumed adaptive run restores the scheme's learning state (scores /
     # cursor) from the checkpoint's own meta instead of the (seed, step)
-    # arithmetic the uniform schemes are rebuilt from
-    smeta = (resume.sampler_state if adaptive and resume is not None
-             else None)
+    # arithmetic the uniform schemes are rebuilt from.  Adaptive plans
+    # never share a stream, so resumes[0] is the only resume.
+    smeta = (resumes[0].sampler_state
+             if adaptive and resumes[0] is not None else None)
     pcfg = pipemod.PipelineConfig(corpus=spec.data.path, batch_size=b,
                                   sampling=spec.scheme, seed=spec.seed,
                                   prefetch=0 if adaptive else spec.prefetch)
-    if plan_.fmt == CSR:
+    if ref.fmt == CSR:
         from ..data import sparse
         with tracer.span("init", DRIVER):
             csr = sparse.open_csr_corpus(spec.data.path)
             pipe = sparse.SparsePipeline(pcfg, start_step=start_step,
                                          tracer=tracer, sampler_meta=smeta)
-        kmax = plan_.kmax if plan_.kmax else csr.kmax
+        kmax = ref.kmax if ref.kmax else csr.kmax
 
         def alloc(k):
             return (np.empty((k, b, kmax), np.int32),
@@ -1502,14 +1575,15 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
                     jnp.zeros((k, b, kmax), jnp.float32),
                     jnp.zeros((k, b), jnp.float32))
 
-        def full_grad_at(w, data_term_only=False):
+        def full_grad_at(problem, w, data_term_only=False):
             return jnp.asarray(sparse.csr_full_grad(
                 problem, csr, np.asarray(w), data_term_only=data_term_only))
 
-        def eval_obj(w):
-            return sparse.csr_objective(problem, csr, np.asarray(w))
+        def eval_objs(pairs):
+            return [sparse.csr_objective(problem, csr, np.asarray(w))
+                    for problem, w in pairs]
 
-        def block_losses(w):
+        def block_losses(problem, w):
             means, _ = sparse.csr_block_losses(problem, csr, np.asarray(w),
                                                b)
             return {"block_losses": means}
@@ -1535,24 +1609,23 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
                     jnp.zeros((k, b), jnp.float32))
 
         def _row_chunks():
-            for lo in range(0, plan_.rows, _EVAL_CHUNK):
+            for lo in range(0, ref.rows, _EVAL_CHUNK):
                 rows = np.asarray(mm[lo:lo + _EVAL_CHUNK])
                 yield rows[:, :n], rows[:, n]
 
-        def full_grad_at(w, data_term_only=False):
+        def full_grad_at(problem, w, data_term_only=False):
             return streaming_full_grad(problem, w, _row_chunks(),
                                        data_term_only=data_term_only)
 
         # the objective pass reads the corpus in blocks of a training
-        # chunk's rows, under the same byte budget
+        # chunk's rows, under the same byte budget; one pass scores every
+        # cell
         block_rows = K * b
 
-        def eval_obj(w):
-            (obj,) = _dense_objectives(mm, block_rows, [(problem, w)],
-                                       tracer)
-            return obj
+        def eval_objs(pairs):
+            return _dense_objectives(mm, block_rows, pairs, tracer)
 
-        def block_losses(w):
+        def block_losses(problem, w):
             # per-BLOCK mean loss in one streamed pass (blocks = the b-row
             # batch slots the contiguous schemes index); numpy margins, no
             # per-block jit calls — the eval chunk does not align with the
@@ -1571,20 +1644,20 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
             return {"block_losses": sums / np.maximum(cnt, 1)}
 
         # what one dense objective pass reads from the memmap
-        objective_args = {"rows": plan_.rows,
-                          "chunks": -(-plan_.rows // block_rows),
-                          "bytes": plan_.rows * mm.shape[1] * mm.itemsize}
+        objective_args = {"rows": ref.rows,
+                          "chunks": -(-ref.rows // block_rows),
+                          "bytes": ref.rows * mm.shape[1] * mm.itemsize}
 
-    sharded = plan_.shards > 1
+    sharded = ref.shards > 1
     if sharded:
         # chunk staging shards the batch axis across the mesh; js (the
         # batch-slot indices) replicates.  The CSR layout never gets here —
         # plan() rejects sharded CSR.
         batch_axes = ((None, "batch", None), (None, "batch"), (None,))
-        gather = plan_.reduction == GATHER
+        gather = ref.reduction == GATHER
         rep = NamedSharding(spec.mesh, PartitionSpec())
         # lint: allow[REPRO002] state placement, not corpus staging
-        state = jax.device_put(state, rep)
+        states = [jax.device_put(st, rep) for st in states]
         # warmup chunks go through the same staging put so the epoch fn
         # compiles against the shardings the live chunks will carry
         warm_put = make_staging_put(spec.mesh, batch_axes, gather=gather)
@@ -1596,136 +1669,86 @@ def _execute_streamed(plan_: ExecutionPlan, resume: Optional[RunResult],
         # their arithmetic identical to the single-host backend's
         host_w = np.asarray
     else:
-        batch_axes = gather = None
         # weighted (adaptive) engines take a trailing (k,) weight vector
         stage_zeros = lambda k: (zeros(k) + (jnp.zeros((k,), jnp.int32),)
                                  + ((jnp.ones((k,), jnp.float32),)
                                     if adaptive else ()))
         host_w = lambda w: w
 
-    def objective(w, epoch):
-        """The objective pass after ``epoch``, as a ``driver:objective``
-        span carrying what a dense pass reads."""
+    def objectives(cells, epoch):
+        """The objective pass after ``epoch`` for the listed cells, as a
+        ``driver:objective`` span carrying what a dense pass reads."""
         with tracer.span("objective", DRIVER, epoch=epoch, **objective_args):
-            return eval_obj(host_w(w))
+            return eval_objs([(plans[i].spec.problem, host_w(states[i].w))
+                              for i in cells])
 
-    eval_fn = objective if spec.record_objective else None
-
-    snapshot_begin = None
-    data_only = cfg.solver == "saag2"
+    snapshot_cells = [i for i, p in enumerate(plans)
+                      if p.cfg.solver in ("svrg", "saag2")]
     with tracer.span("warmup", DRIVER):
         # compile every chunk shape outside the timed region
         for k in sorted({K, m % K} - {0}):
-            dummy = init_state(cfg.solver, jnp.zeros(n, jnp.float32), m)
-            if sharded:
-                # lint: allow[REPRO002] warmup placement
-                dummy = jax.device_put(dummy, rep)
-            jax.block_until_ready(epoch_fn(dummy, *stage_zeros(k)))
-        if plan_.fmt != CSR:
-            _warm_dense_objective(problem, n, plan_.rows, block_rows,
-                                  host_w(state.w))
-        if cfg.solver in ("svrg", "saag2"):
+            for fn, p in zip(fns, plans):
+                dummy = init_state(p.cfg.solver, jnp.zeros(n, jnp.float32), m)
+                if sharded:
+                    # lint: allow[REPRO002] warmup placement
+                    dummy = jax.device_put(dummy, rep)
+                jax.block_until_ready(fn(dummy, *stage_zeros(k)))
+        if ref.fmt != CSR:
+            for p, st in zip(plans, states):
+                _warm_dense_objective(p.spec.problem, n, ref.rows,
+                                      block_rows, host_w(st.w))
+        for i in snapshot_cells:
             # the snapshot full-grad stream compiles too — keep it out of
             # epoch 1
-            jax.block_until_ready(full_grad_at(jnp.zeros(n, jnp.float32),
-                                               data_term_only=data_only))
-    if cfg.solver in ("svrg", "saag2"):
-        def snapshot_begin(st):
-            with tracer.span("snapshot", DRIVER):
-                st = epoch_begin(
-                    problem, cfg, st,
-                    lambda w: full_grad_at(host_w(w),
-                                           data_term_only=data_only))
-            # keep every state leaf on the mesh: a default-device snapshot
-            # gradient would make the donated epoch call re-specialize
-            # lint: allow[REPRO002] snapshot-state mesh placement
-            return jax.device_put(st, rep) if sharded else st
+            jax.block_until_ready(full_grad_at(
+                plans[i].spec.problem, jnp.zeros(n, jnp.float32),
+                data_term_only=plans[i].cfg.solver == "saag2"))
+
+    def snapshot(i, st):
+        problem, cfg = plans[i].spec.problem, plans[i].cfg
+        with tracer.span("snapshot", DRIVER):
+            st = epoch_begin(
+                problem, cfg, st,
+                lambda w: full_grad_at(problem, host_w(w),
+                                       data_term_only=cfg.solver == "saag2"))
+        # keep every state leaf on the mesh: a default-device snapshot
+        # gradient would make the donated epoch call re-specialize
+        # lint: allow[REPRO002] snapshot-state mesh placement
+        return jax.device_put(st, rep) if sharded else st
 
     # cumulative trace across resumes, as in the resident path
-    prefix = [] if resume is None else [float(h) for h in resume.history]
-    rck = _RunCheckpointer(plan_, done0, epochs, tracer)
+    prefixes = [[] if r is None else [float(h) for h in r.history]
+                for r in resumes]
+    histories: List[List[float]] = [[] for _ in plans]
+    recording = [i for i, p in enumerate(plans) if p.spec.record_objective]
+    compute_s = [0.0] * S
+    train_s = 0.0
+    rcks = [_RunCheckpointer(p, done0, epochs, t)
+            for p, t in zip(plans, tracers)]
 
-    def on_epoch(e, st, hist):
+    def sampler_state(e):
         if adaptive:
             # the adaptive driver drains exactly m draws per epoch and
-            # applies observe() BEFORE this hook, so the scheme's own meta
-            # (scores / cursor included) is exact here
-            smeta_e = pipe.sampler_meta()
-        else:
-            # deterministic count of CONSUMED batches — the prefetch
-            # producer may have advanced the live sampler a few steps
-            smeta_e = {"scheme": plan_.scheme_name, "seed": spec.seed,
-                       "step": start_step + m * (e + 1)}
-        rck.after_epoch(e, st, smeta_e, prefix + hist, pipe.stats)
+            # applies observe() BEFORE the checkpoint, so the scheme's own
+            # meta (scores / cursor included) is exact here
+            return pipe.sampler_meta()
+        # deterministic count of CONSUMED batches — the prefetch producer
+        # may have advanced the live sampler a few steps
+        return {"scheme": ref.scheme_name, "seed": spec.seed,
+                "step": start_step + m * (e + 1)}
 
-    try:
-        state, history, compute_s, train_s = _drive_chunked(
-            pipe, epoch_fn, state, m=m, K=K, epochs=epochs,
-            start_step=start_step, alloc=alloc, fill=fill,
-            snapshot_begin=snapshot_begin, eval_fn=eval_fn,
-            mesh=spec.mesh if sharded else None, batch_axes=batch_axes,
-            gather=bool(gather), on_epoch=on_epoch, tracer=tracer,
-            epoch0=done0, step_rule=plan_.step_rule,
-            adaptive=adaptive,
-            feedback=(block_losses if adaptive
-                      and scheme_obj.wants_feedback else None))
-        if cfg.step_mode == LINE_SEARCH:
-            # the trial ladder runs fused inside the chunk jit (one ladder
-            # per batch), so the driver books the invocation count
-            tracer.metrics.counter("ls.invocations").inc(m * epochs)
-    finally:
-        rck.finish()
-
-    objective = (history[-1] if history
-                 else objective(state.w, done0 + epochs - 1))
-    return RunResult(
-        plan=plan_, objective=objective,
-        history=np.asarray(prefix + history),
-        w=np.asarray(state.w), solver_state=state,
-        sampler_state=(pipe.sampler_meta() if adaptive else
-                       {"scheme": plan_.scheme_name, "seed": spec.seed,
-                        "step": start_step + m * epochs}),
-        epochs_run=epochs, epochs_done=done0 + epochs, stats=pipe.stats,
-        train_s=train_s, compute_s=compute_s)
-
-
-def _drive_chunked(pipe, epoch_fn, state, *, m: int, K: int, epochs: int,
-                   start_step: int, alloc: Callable, fill: Callable,
-                   snapshot_begin: Optional[Callable],
-                   eval_fn: Optional[Callable], mesh: Optional[Mesh] = None,
-                   batch_axes=None, gather: bool = False,
-                   on_epoch: Optional[Callable] = None,
-                   tracer: Tracer = NULL_TRACER, epoch0: int = 0,
-                   step_rule: Optional[str] = None, adaptive: bool = False,
-                   feedback: Optional[Callable] = None,
-                   ) -> Tuple[SolverState, List[float], float, float]:
-    """The shared streaming engine under the dense and sparse backends:
-    group the pipeline's batch stream into <=K-batch chunks (never crossing
-    an epoch boundary — snapshot solvers refresh state between epochs),
-    double-buffer them host->device (DeviceStager), and scan each chunk in
-    one device call.
-
-    ``alloc(k)`` builds contiguous host staging buffers for a k-batch chunk
-    (batches are written straight in — one copy, not stack-then-slice);
-    ``fill(bufs, i, batch)`` writes batch i; ``eval_fn(w, epoch)`` is the
-    per-epoch objective probe, run OUTSIDE the timers (it opens its own
-    ``driver:objective`` span); ``on_epoch(e, state, history)``
-    is the checkpoint hook, also untimed, called at every epoch boundary.
-    Returns (state, history, compute_s, train_s).
-
-    With ``adaptive=True`` the pipeline yields ``(payload, j, weight)``
-    triples (the Scheme protocol's adaptive surface) and the driver switches
-    to :func:`_drive_chunked_adaptive` — epoch-scoped staging plus the
-    ``feedback`` -> ``pipe.observe`` loop.
-    """
-    from ..data import pipeline as pipemod
-
-    if adaptive:
-        return _drive_chunked_adaptive(
-            pipe, epoch_fn, state, m=m, K=K, epochs=epochs, alloc=alloc,
-            fill=fill, snapshot_begin=snapshot_begin, eval_fn=eval_fn,
-            feedback=feedback, on_epoch=on_epoch, tracer=tracer,
-            epoch0=epoch0, step_rule=step_rule)
+    def end_epoch(e):
+        """Objective pass and checkpoints after epoch ``e``, untimed."""
+        if recording:
+            for i, v in zip(recording, objectives(recording, done0 + e)):
+                histories[i].append(float(v))
+        if adaptive and scheme_obj.wants_feedback:
+            with tracer.span("feedback", DRIVER, epoch=done0 + e):
+                pipe.observe(block_losses(ref.spec.problem, states[0].w))
+        for i in range(S):
+            rcks[i].after_epoch(e, states[i], sampler_state(e),
+                                prefixes[i] + histories[i],
+                                _cell_stats(pipe.stats, S))
 
     def host_chunks():
         it = iter(pipe)
@@ -1744,67 +1767,100 @@ def _drive_chunked(pipe, epoch_fn, state, *, m: int, K: int, epochs: int,
         js = (np.arange(j0, j0 + bufs[0].shape[0]) % m).astype(np.int32)
         return tuple(bufs) + (js,)
 
-    if mesh is not None:
-        # mesh-aware staging: each chunk lands sharded on the batch axis
-        # (per-device H2D divided by the mesh width); 'gather' mode then
-        # reshards to replicated inside the staging thread
-        stager = pipemod.DeviceStager(host_chunks(), convert=convert,
-                                      depth=2, stats=pipe.stats, mesh=mesh,
-                                      batch_axes=batch_axes, gather=gather,
-                                      tracer=tracer)
-    else:
-        stager = pipemod.DeviceStager(host_chunks(), put=_put_blocking,
-                                      convert=convert, depth=2,
-                                      stats=pipe.stats, tracer=tracer)
-    chunks_iter = iter(stager)
-    history: List[float] = []
-    compute_s = 0.0
-    train_s = 0.0
+    def on_epoch(e, st):
+        states[0] = st
+        end_epoch(e)
+
     try:
-        for e in range(epochs):
-            # the epoch timespan IS the train_s measurement (snapshot
-            # refresh + chunk waits + device calls; eval/checkpoint hooks
-            # stay outside, as before); each chunk's device call is its
-            # own compute span — the same dur feeds compute_s
-            with tracer.timespan("train_epoch", EPOCH,
-                                 epoch=epoch0 + e) as se:
-                if snapshot_begin is not None:
-                    state = snapshot_begin(state)
-                done = 0
-                while done < m:
-                    args = next(chunks_iter)
-                    with tracer.timespan("chunk", COMPUTE,
-                                         epoch=epoch0 + e, first_batch=done,
-                                         step_rule=step_rule) as sc:
-                        state = epoch_fn(state, *args)
-                        jax.block_until_ready(state.w)
-                        sc.set(batches=int(args[0].shape[0]))
-                    compute_s += sc.dur
-                    done += args[0].shape[0]
-            train_s += se.dur
-            if eval_fn is not None:
-                history.append(float(eval_fn(state.w, epoch0 + e)))
-            if on_epoch is not None:
-                on_epoch(e, state, history)               # untimed
+        if adaptive:
+            states[0], compute_s[0], train_s = _drive_chunked_adaptive(
+                pipe, fns[0], states[0], m=m, K=K, epochs=epochs,
+                alloc=alloc, fill=fill,
+                snapshot_begin=((lambda st: snapshot(0, st))
+                                if snapshot_cells else None),
+                on_epoch=on_epoch, tracer=tracer, epoch0=done0,
+                step_rule=ref.step_rule)
+        else:
+            if sharded:
+                # mesh-aware staging: each chunk lands sharded on the batch
+                # axis (per-device H2D divided by the mesh width); 'gather'
+                # mode then reshards to replicated inside the staging
+                # thread
+                stager = pipemod.DeviceStager(
+                    host_chunks(), convert=convert, depth=2,
+                    stats=pipe.stats, mesh=spec.mesh, batch_axes=batch_axes,
+                    gather=gather, tracer=tracer)
+            else:
+                stager = pipemod.DeviceStager(
+                    host_chunks(), put=_put_blocking, convert=convert,
+                    depth=2, stats=pipe.stats, tracer=tracer)
+            chunks_iter = iter(stager)
+            try:
+                for e in range(epochs):
+                    # the epoch timespan IS the train_s measurement
+                    # (snapshot refresh + chunk waits + device calls; the
+                    # end-of-epoch pass stays outside); each cell's device
+                    # call on a chunk is its own compute span — the same
+                    # dur feeds its compute_s
+                    with tracer.timespan("train_epoch", EPOCH,
+                                         epoch=done0 + e) as se:
+                        for i in snapshot_cells:
+                            states[i] = snapshot(i, states[i])
+                        done = 0
+                        while done < m:
+                            args = next(chunks_iter)
+                            k = int(args[0].shape[0])
+                            for i, p in enumerate(plans):
+                                with tracers[i].timespan(
+                                        "chunk", COMPUTE, epoch=done0 + e,
+                                        first_batch=done,
+                                        step_rule=p.step_rule) as sc:
+                                    states[i] = fns[i](states[i], *args)
+                                    jax.block_until_ready(states[i].w)
+                                    sc.set(batches=k)
+                                compute_s[i] += sc.dur
+                            done += k
+                    train_s += se.dur
+                    end_epoch(e)
+            finally:
+                stager.close()
+                pipe.close()
     finally:
-        stager.close()
-        pipe.close()
-    return state, history, compute_s, train_s
+        for rck in rcks:
+            rck.finish()
+    for t, p in zip(tracers, plans):
+        if p.cfg.step_mode == LINE_SEARCH:
+            # the trial ladder runs fused inside the chunk jit (one ladder
+            # per batch), so the driver books the invocation count
+            t.metrics.counter("ls.invocations").inc(m * epochs)
+
+    finals = [i for i in range(S) if not histories[i]]
+    final = (dict(zip(finals, objectives(finals, done0 + epochs - 1)))
+             if finals else {})
+    return [RunResult(
+        plan=p, objective=(histories[i][-1] if histories[i]
+                           else float(final[i])),
+        history=np.asarray(prefixes[i] + histories[i]),
+        w=np.asarray(states[i].w), solver_state=states[i],
+        sampler_state=sampler_state(epochs - 1), epochs_run=epochs,
+        epochs_done=done0 + epochs,
+        stats=_cell_stats(pipe.stats, S), train_s=train_s / S,
+        compute_s=compute_s[i]) for i, p in enumerate(plans)]
 
 
 def _drive_chunked_adaptive(pipe, epoch_fn, state, *, m: int, K: int,
                             epochs: int, alloc: Callable, fill: Callable,
                             snapshot_begin: Optional[Callable],
-                            eval_fn: Optional[Callable],
-                            feedback: Optional[Callable],
-                            on_epoch: Optional[Callable] = None,
+                            on_epoch: Callable,
                             tracer: Tracer = NULL_TRACER, epoch0: int = 0,
                             step_rule: Optional[str] = None,
-                            ) -> Tuple[SolverState, List[float], float, float]:
-    """The adaptive-scheme variant of :func:`_drive_chunked`.
+                            ) -> Tuple[SolverState, float, float]:
+    """The chunk loop of the adaptive schemes, for the one cell their
+    plans run.  Returns ``(state, compute_s, train_s)``.
 
-    Differences from the uniform driver, all serving one invariant — the
-    scheme state must be EXACT at every epoch boundary:
+    Differences from the uniform loop in :func:`_drive_streamed`, all
+    serving one invariant — the scheme state must be EXACT at every epoch
+    boundary:
 
     * the pipeline yields ``(payload, j, weight)`` triples: the scheme
       chooses the gradient-table slot ``j`` (it is NOT ``step % m``) and
@@ -1813,12 +1869,9 @@ def _drive_chunked_adaptive(pipe, epoch_fn, state, *, m: int, K: int,
     * the :class:`DeviceStager` is scoped to ONE epoch: its producer thread
       may only run ahead within the epoch, so after the epoch's chunks
       drain, the (prefetch=0) pipeline has consumed exactly ``m`` draws —
-      ``feedback(w)`` statistics then land via ``pipe.observe`` at a
-      deterministic point in the draw stream, and ``pipe.sampler_meta()``
-      is checkpoint-exact when ``on_epoch`` fires;
-    * ``feedback`` runs BEFORE ``on_epoch`` so the checkpoint carries the
-      post-observe learning state (scores/cursor) — resume replays epoch
-      ``e+1`` bit-identically.
+      the feedback statistics ``on_epoch(e, state)`` hands to
+      ``pipe.observe`` then land at a deterministic point in the draw
+      stream, and ``pipe.sampler_meta()`` is checkpoint-exact there.
     """
     from ..data import pipeline as pipemod
 
@@ -1838,7 +1891,6 @@ def _drive_chunked_adaptive(pipe, epoch_fn, state, *, m: int, K: int,
             yield bufs + (js, ws)
             done += k
 
-    history: List[float] = []
     compute_s = 0.0
     train_s = 0.0
     try:
@@ -1862,16 +1914,10 @@ def _drive_chunked_adaptive(pipe, epoch_fn, state, *, m: int, K: int,
                     done += args[0].shape[0]
             stager.close()   # producer joined: the sampler is quiescent
             train_s += se.dur
-            if eval_fn is not None:
-                history.append(float(eval_fn(state.w, epoch0 + e)))
-            if feedback is not None:
-                with tracer.span("feedback", DRIVER, epoch=epoch0 + e):
-                    pipe.observe(feedback(state.w))       # untimed
-            if on_epoch is not None:
-                on_epoch(e, state, history)               # untimed
+            on_epoch(e, state)                            # untimed
     finally:
         pipe.close()
-    return state, history, compute_s, train_s
+    return state, compute_s, train_s
 
 
 def _put_blocking(host):

@@ -191,19 +191,15 @@ def _solver_direction(problem: ERMProblem, cfg: SolverConfig,
 
 def batch_step(problem: ERMProblem, cfg: SolverConfig, state: SolverState,
                Xb: jax.Array, yb: jax.Array, j: jax.Array,
-               step0: Optional[jax.Array] = None,
                weight: Optional[jax.Array] = None) -> SolverState:
     """Apply one solver update using batch ``j`` with data (Xb, yb).
 
-    ``step0`` (optional traced scalar) overrides the config's static initial
-    step — the per-cell lift the super-cell engines vmap over; ``None``
-    keeps the solo program byte-for-byte.  ``weight`` (optional traced
-    scalar) rescales the batch-mean data gradient — the unbiasedness
-    correction the weighted schemes (``BatchIndices.weight``) emit: for
-    importance sampling it is ``1/(m p_j)``, for stochastic batch size
-    ``b / b_t`` (zero-padded rows contribute zero to ``X^T dloss``, so the
-    padded mean only needs re-normalizing).  ``None`` keeps the uniform
-    program byte-for-byte."""
+    ``weight`` (optional traced scalar) rescales the batch-mean data
+    gradient — the unbiasedness correction the weighted schemes
+    (``BatchIndices.weight``) emit: for importance sampling it is
+    ``1/(m p_j)``, for stochastic batch size ``b / b_t`` (zero-padded rows
+    contribute zero to ``X^T dloss``, so the padded mean only needs
+    re-normalizing).  ``None`` keeps the uniform program byte-for-byte."""
     w = state.w
     gd = problem.batch_grad_data(w, Xb, yb)
     gd_snap = (problem.batch_grad_data(state.snapshot, Xb, yb)
@@ -213,20 +209,19 @@ def batch_step(problem: ERMProblem, cfg: SolverConfig, state: SolverState,
         gd_snap = None if gd_snap is None else gd_snap * weight
     v, g, new_state = _solver_direction(problem, cfg, state, j, gd, gd_snap)
     alpha = _step_rule(cfg).pick(step_rules.dense_probe(problem, Xb, yb),
-                                 w, v, g, step0=step0)
+                                 w, v, g)
     return new_state._replace(w=w - alpha * v)
 
 
 def sparse_batch_step(problem: ERMProblem, cfg: SolverConfig,
                       state: SolverState, cols: jax.Array, vals: jax.Array,
                       yb: jax.Array, j: jax.Array,
-                      step0: Optional[jax.Array] = None,
                       weight: Optional[jax.Array] = None) -> SolverState:
     """One solver update from a padded-ELL CSR batch — the corpus is never
     densified.  (cols, vals): (b, kmax) per ``repro.data.sparse.SparseBatch``;
     the update rules are shared with the dense path via
     :func:`_solver_direction`, and line search backtracks on the sparse
-    batch objective.  ``step0`` / ``weight`` as in :func:`batch_step`."""
+    batch objective.  ``weight`` as in :func:`batch_step`."""
     w = state.w
     gd = problem.ell_batch_grad_data(w, cols, vals, yb)
     gd_snap = (problem.ell_batch_grad_data(state.snapshot, cols, vals, yb)
@@ -236,7 +231,7 @@ def sparse_batch_step(problem: ERMProblem, cfg: SolverConfig,
         gd_snap = None if gd_snap is None else gd_snap * weight
     v, g, new_state = _solver_direction(problem, cfg, state, j, gd, gd_snap)
     alpha = _step_rule(cfg).pick(
-        step_rules.ell_probe(problem, cols, vals, yb), w, v, g, step0=step0)
+        step_rules.ell_probe(problem, cols, vals, yb), w, v, g)
     return new_state._replace(w=w - alpha * v)
 
 
@@ -503,6 +498,11 @@ def make_epoch_fn(problem: ERMProblem, cfg: SolverConfig,
     return epoch_chunk
 
 
+# the benchmark's fault harness (bench/harness/faults.py) clears this cache
+# under its former name; every super-cell cell runs make_epoch_fn
+make_supercell_epoch_fn = make_epoch_fn
+
+
 def make_resident_epoch_fn(problem: ERMProblem, cfg: SolverConfig,
                            scheme: str, batch_size: int,
                            rows: Optional[int] = None):
@@ -530,150 +530,6 @@ def make_resident_epoch_fn(problem: ERMProblem, cfg: SolverConfig,
             "planner keeps sharded placements on the eager engines")
     return partial(_run_one_epoch, problem, cfg, scheme, batch_size,
                    rows=rows)
-
-
-# ---------------------------------------------------------------------------
-# super-cell engines: one staged chunk drives S cells (repro.core.supercell)
-# ---------------------------------------------------------------------------
-#
-# Bit-parity discipline (the supercell contract, CI-proven in
-# tests/test_supercell.py): the vmapped cell body is the SAME scan the solo
-# engines run — same unroll, same batch_step arithmetic — with only the
-# initial step lifted to a traced per-cell scalar.  But batching the
-# per-cell matvecs into cross-cell matmuls lets XLA pick a different
-# tiling/reduction order, and the drift is shape-dependent (exact at
-# 600x12/batch-50, ~1e-7 at 100k x 64/batch-500) — not contractual for
-# ANY solver, and guaranteed for snapshot solvers (svrg/saag2, whose
-# in-scan snapshot term diverges by epoch 2 even at small shapes; they
-# raise below).  The super-cell driver therefore runs EVERY lane through
-# the SOLO engines by default — the very same lru-cached compiled
-# callables a solo execute() uses — against the shared staged chunk, so
-# parity is structural while the access amortization is identical.  The
-# vmapped engines here are the opt-in (execute_supercell(...,
-# vmap_lanes=True)) batched-compute path for snapshot-free lanes.
-
-@lru_cache(maxsize=32)
-def make_supercell_epoch_fn(problem: ERMProblem, cfg: SolverConfig):
-    """Vmapped chunked epoch engine: ``(stateS, Xc, yc, js, step0S) ->
-    stateS`` with a leading cell axis S on the state and step sizes.
-
-    ONE staged chunk (``Xc: (K, b, n)``, shared across cells — in_axes
-    ``None``) drives S solver trajectories per device call; access, convert
-    and H2D cost are paid once and amortized S-fold.  ``cfg.step_size`` is
-    dead under the lift: callers normalize it (``_lane_cfg`` in
-    :mod:`repro.core.supercell`) so lanes differing only in step size share
-    one compiled callable.  With ``cfg.sparse`` the signature is
-    ``(stateS, colsc, valsc, yc, js, step0S)`` over padded-ELL chunks.
-
-    ``stateS`` is donated, exactly like :func:`make_epoch_fn`.
-    """
-    if cfg.use_fused:
-        raise ValueError(
-            "use_fused applies to the device-resident run(): the chunked "
-            "super-cell engine consumes staged batches — nothing to fuse")
-    if _needs_snapshot(cfg.solver):
-        raise ValueError(
-            f"{cfg.solver} carries an in-scan snapshot gradient, which a "
-            f"vmapped cell axis batches to a different reduction order — "
-            f"super-cell drivers run snapshot solvers per cell through the "
-            f"solo engines (same staged chunk, structural bit-parity)")
-    sequential_ls = (cfg.step_mode == LINE_SEARCH
-                     and cfg.ls_mode == SEQUENTIAL)
-    unroll = 1 if sequential_ls else 8
-
-    if cfg.sparse:
-        def cell(state, colsc, valsc, yc, js, step0):
-            def body(st, inp):
-                cols, vals, yb, j = inp
-                return sparse_batch_step(problem, cfg, st, cols, vals, yb,
-                                         j, step0=step0), None
-            out, _ = jax.lax.scan(body, state, (colsc, valsc, yc, js),
-                                  unroll=unroll)
-            return out
-
-        @partial(jax.jit, donate_argnums=(0,))
-        def sparse_supercell_chunk(stateS, colsc, valsc, yc, js, step0S):
-            return jax.vmap(cell, in_axes=(0, None, None, None, None, 0))(
-                stateS, colsc, valsc, yc, js, step0S)
-        return sparse_supercell_chunk
-
-    def cell(state, Xc, yc, js, step0):
-        def body(st, inp):
-            Xb, yb, j = inp
-            return batch_step(problem, cfg, st, Xb, yb, j,
-                              step0=step0), None
-        out, _ = jax.lax.scan(body, state, (Xc, yc, js), unroll=unroll)
-        return out
-
-    @partial(jax.jit, donate_argnums=(0,))
-    def supercell_chunk(stateS, Xc, yc, js, step0S):
-        return jax.vmap(cell, in_axes=(0, None, None, None, 0))(
-            stateS, Xc, yc, js, step0S)
-    return supercell_chunk
-
-
-@partial(jax.jit, static_argnames=("problem", "cfg", "scheme", "batch_size"),
-         donate_argnums=(4,))
-def _run_supercell_epoch(problem: ERMProblem, cfg: SolverConfig, scheme: str,
-                         batch_size: int, stateS: SolverState, X: jax.Array,
-                         y: jax.Array, key: jax.Array,
-                         step0S: jax.Array) -> SolverState:
-    """Resident epoch over a leading cell axis S (snapshot-free solvers —
-    see :func:`make_supercell_resident_fn`).
-
-    The per-cell body is :func:`_run_one_epoch`'s scan verbatim — same
-    in-graph batch selection, same no-unroll parity surface — vmapped over
-    (state, step0) with the resident corpus and the epoch key shared.
-    """
-    l = X.shape[0]
-    m = samplers.num_batches(l, batch_size)
-    contiguous = scheme in (samplers.CYCLIC, samplers.SYSTEMATIC)
-    if contiguous:
-        starts = samplers.batch_slice_starts(scheme, key, l, batch_size)
-    else:
-        idx_mat = samplers.epoch_indices(scheme, key, l, batch_size)
-
-    def cell(state, step0):
-        def body(st, j):
-            if contiguous:
-                Xb = jax.lax.dynamic_slice(
-                    X, (starts[j], 0), (batch_size, X.shape[1]))
-                yb = jax.lax.dynamic_slice(y, (starts[j],), (batch_size,))
-            else:
-                Xb, yb = gather_batch(X, y, idx_mat[j])
-            return batch_step(problem, cfg, st, Xb, yb, j,
-                              step0=step0), None
-        out, _ = jax.lax.scan(body, state, jnp.arange(m))
-        return out
-
-    return jax.vmap(cell)(stateS, step0S)
-
-
-def make_supercell_resident_fn(problem: ERMProblem, cfg: SolverConfig,
-                               scheme: str, batch_size: int):
-    """Resident super-cell epoch: ``(stateS, X, y, key, step0S) -> stateS``.
-
-    The corpus is staged ONCE for all S cells; the epoch body is vmapped
-    over (state, step0) with the corpus and the epoch key shared.
-    ``stateS`` is donated.  Snapshot solvers are rejected like in
-    :func:`make_supercell_epoch_fn` — the super-cell driver runs them per
-    cell through :func:`make_resident_epoch_fn` instead.
-    """
-    if cfg.sparse:
-        raise ValueError(
-            "resident mode stages a dense (l, n) corpus; CSR corpora keep "
-            "the chunked super-cell engine")
-    if cfg.use_fused:
-        raise ValueError(
-            "fused kernels schedule their own per-cell DMA; the super-cell "
-            "planner falls back to solo execution for kernel='fused'")
-    if _needs_snapshot(cfg.solver):
-        raise ValueError(
-            f"{cfg.solver} carries an in-scan snapshot gradient, which a "
-            f"vmapped cell axis batches to a different reduction order — "
-            f"super-cell drivers run snapshot solvers per cell through the "
-            f"solo engines (same staged corpus, structural bit-parity)")
-    return partial(_run_supercell_epoch, problem, cfg, scheme, batch_size)
 
 
 def streaming_full_grad(problem: ERMProblem, w, batch_iter, *, data_term_only=False):

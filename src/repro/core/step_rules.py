@@ -170,25 +170,14 @@ def validate_ls(step_size: float, shrink: float, c: float, max_iter: int):
         raise ValueError(f"ls_max_iter must be >= 1, got {max_iter!r}")
 
 
-def _alpha0(rule, w: jax.Array, step0: Optional[jax.Array]) -> jax.Array:
-    """The rule's initial step: the static config value, or — for the
-    super-cell vmapped engines, where cells in one lane differ ONLY in step
-    size — a traced per-cell scalar lifted out of the config.  Either way
-    the downstream arithmetic is the same f32 ops on the same value, which
-    is what keeps lifted trajectories bit-identical to solo runs."""
-    if step0 is None:
-        return jnp.asarray(rule.step_size, w.dtype)
-    return jnp.asarray(step0, w.dtype)
-
-
 class ConstantStep(NamedTuple):
     """Fixed step size (paper default: 1/L)."""
     step_size: float
     needs_probe: bool = False
 
     def pick(self, probe: Optional[BatchProbe], w: jax.Array, v: jax.Array,
-             g: jax.Array, step0: Optional[jax.Array] = None) -> jax.Array:
-        return _alpha0(self, w, step0)
+             g: jax.Array) -> jax.Array:
+        return jnp.asarray(self.step_size, w.dtype)
 
 
 class BacktrackingLS(NamedTuple):
@@ -204,7 +193,7 @@ class BacktrackingLS(NamedTuple):
     needs_probe: bool = True
 
     def pick(self, probe: BatchProbe, w: jax.Array, v: jax.Array,
-             g: jax.Array, step0: Optional[jax.Array] = None) -> jax.Array:
+             g: jax.Array) -> jax.Array:
         obj = probe.objective
         f0 = obj(w)
         gv = jnp.dot(g, v)
@@ -218,7 +207,7 @@ class BacktrackingLS(NamedTuple):
             alpha, it = carry
             return alpha * self.shrink, it + 1
 
-        alpha0 = _alpha0(self, w, step0)
+        alpha0 = jnp.asarray(self.step_size, w.dtype)
         alpha, _ = jax.lax.while_loop(cond, body, (alpha0, 0))
         # If v is not a descent direction on this batch (<g, v> <= 0) the
         # Armijo condition is vacuous and the loop would return the FULL
@@ -260,8 +249,8 @@ class VectorizedLS(NamedTuple):
     needs_probe: bool = True
 
     def pick(self, probe: BatchProbe, w: jax.Array, v: jax.Array,
-             g: jax.Array, step0: Optional[jax.Array] = None) -> jax.Array:
-        alpha0 = _alpha0(self, w, step0)
+             g: jax.Array) -> jax.Array:
+        alpha0 = jnp.asarray(self.step_size, w.dtype)
         # repeated multiplication — NOT cumprod (a log-depth associative
         # scan) or shrink**k — so every rung is bit-identical to the value
         # the sequential while_loop would have produced; max_iter is static,

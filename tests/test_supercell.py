@@ -115,36 +115,13 @@ def test_supercell_sparse_csr_matches_solo(csr_corpus):
     _assert_cellwise_identical(solos, supers)
 
 
-def test_single_cell_supercell_is_exactly_solo(corpus):
-    p = plan(_spec(DataSource.corpus(corpus)))
+@pytest.mark.parametrize("placement", [STREAMED, RESIDENT])
+def test_single_cell_supercell_is_exactly_solo(corpus, placement):
+    p = plan(_spec(DataSource.corpus(corpus), placement=placement))
     [r] = execute_supercell([p])
     s = execute(p)
     np.testing.assert_array_equal(s.w, r.w)
     np.testing.assert_array_equal(s.history, r.history)
-
-
-def test_vmap_lanes_opt_in_close_but_not_contractual(corpus):
-    """vmap_lanes batches snapshot-free lanes; trajectories stay within
-    float32-ulp distance of solo but the bit-exact contract is only made
-    by the default mode."""
-    plans = [plan(_spec(DataSource.corpus(corpus), solver="saga", step=a))
-             for a in (0.02, 0.05, 0.08)]
-    solos = [execute(p) for p in plans]
-    supers = execute_supercell(plans, vmap_lanes=True)
-    for s, r in zip(solos, supers):
-        np.testing.assert_allclose(s.w, r.w, rtol=0, atol=1e-5)
-
-
-def test_supercell_engines_reject_snapshot_solvers():
-    from repro.core.solvers import (SolverConfig, make_supercell_epoch_fn,
-                                    make_supercell_resident_fn)
-    from repro.core.erm import ERMProblem
-    problem = ERMProblem(loss="logistic", reg=1e-3)
-    cfg = SolverConfig(solver="svrg", step_mode="constant", step_size=0.05)
-    with pytest.raises(ValueError, match="snapshot"):
-        make_supercell_epoch_fn(problem, cfg)
-    with pytest.raises(ValueError, match="snapshot"):
-        make_supercell_resident_fn(problem, cfg, "systematic", B)
 
 
 # ------------------------------------------------------------ coalescer ----
@@ -260,8 +237,10 @@ def test_supercell_rejects_resume_from_different_plan(corpus):
 
 # ------------------------------------------------- per-cell attribution ----
 
-def test_supercell_per_cell_timeline_reconciles(corpus, tmp_path):
+@pytest.mark.parametrize("placement", [STREAMED, RESIDENT])
+def test_supercell_per_cell_timeline_reconciles(corpus, tmp_path, placement):
     specs = [_spec(DataSource.corpus(corpus), solver=s, step=a,
+                   placement=placement,
                    trace=TracePolicy(path=tmp_path / f"cell{i}.json"))
              for i, (s, a) in enumerate(
                  (("saga", 0.05), ("saga", 0.1), ("svrg", 0.05)))]
@@ -284,8 +263,12 @@ def test_supercell_per_cell_timeline_reconciles(corpus, tmp_path):
     assert all(r.train_s == results[0].train_s for r in results)
 
 
-def test_supercell_stats_are_amortized(corpus):
-    plans = [plan(_spec(DataSource.corpus(corpus), step=0.01 * (i + 1)))
+@pytest.mark.parametrize("placement", [STREAMED, RESIDENT])
+def test_supercell_stats_are_amortized(corpus, placement):
+    # no read-ahead: a stream then reads exactly the batches it trains on,
+    # so solo and super-cell read counts compare exactly
+    plans = [plan(_spec(DataSource.corpus(corpus), step=0.01 * (i + 1),
+                        placement=placement, prefetch=0))
              for i in range(4)]
     solo = execute(plans[0])
     supers = execute_supercell(plans)
