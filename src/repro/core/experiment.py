@@ -1352,14 +1352,9 @@ def _drive_resident(plans: List[ExecutionPlan],
             sampling=spec.scheme, seed=spec.seed, prefetch=0, resident=True),
             tracer=tracer)
         stats = pipe.stats
-        rows = pipe.read_all()
-        n = ref.features
-        # contiguity copies BEFORE the timer: device_put of a strided view
-        # would hide a host-side memcpy inside the H2D number
-        with tracer.span("layout", DRIVER) as sp:
-            Xh = np.ascontiguousarray(rows[:, :n])
-            yh = np.ascontiguousarray(rows[:, n])
-            sp.set(bytes=Xh.nbytes + yh.nbytes)
+        # contiguous X and y straight off the file, in one pass (the read
+        # lays them out): nothing is copied inside the H2D timer
+        Xh, yh = pipe.read_all()
         if sharded:
             X, y, h2d_dt = _stage_resident_sharded(ref, Xh, yh, stats,
                                                    tracer)
@@ -1374,9 +1369,8 @@ def _drive_resident(plans: List[ExecutionPlan],
             stats.record_h2d(h2d_dt, Xh.nbytes + yh.nbytes)
         # the host copies are dead once staged: free their pages here, on
         # the driver's clock, and not unseen at return
-        with tracer.span("release", DRIVER,
-                         bytes=rows.nbytes + Xh.nbytes + yh.nbytes):
-            del rows, Xh, yh
+        with tracer.span("release", DRIVER, bytes=Xh.nbytes + yh.nbytes):
+            del Xh, yh
 
     # 'psum' keeps the padded corpus sharded through the scan, so the epoch
     # engine needs the true row count (schedule, clamping, masked snapshot

@@ -260,10 +260,15 @@ def test_traced_job_names_every_phase_of_execute(big_dense_corpus,
     assert [e.args["epoch"] for e in objective] == [0, 1, 2, 3]
     nbytes = BIG_ROWS * (BIG_FEATS + 1) * 4
     if placement == RESIDENT:
-        (layout,) = _spans(res, DRIVER, "layout")
-        assert layout.args["bytes"] == nbytes
+        # the read lays X and y out itself: a file corpus has no layout copy
+        assert not _spans(res, DRIVER, "layout")
+        (read,) = _spans(res, ACCESS, "read_all")
+        assert read.args["bytes"] == nbytes
+        assert read.args["blocks"] == res.timeline.metrics["counters"][
+            "read_all.blocks"] >= 1
+        assert 1 <= read.args["workers"] <= min(8, read.args["blocks"])
         (release,) = _spans(res, DRIVER, "release")
-        assert release.args["bytes"] == 2 * nbytes
+        assert release.args["bytes"] == nbytes
     else:
         # the pass reads blocks of one training chunk's rows
         block_rows = res.plan.chunk * spec.batch_size

@@ -71,9 +71,9 @@ def test_resident_pipeline_refuses_batch_iteration(tmp_path):
         pipe.read_batch()
     with pytest.raises(RuntimeError, match="resident"):
         next(iter(pipe))
-    rows = pipe.read_all()          # the one sanctioned access
-    assert rows.shape == (40, 5)
-    assert pipe.stats.bytes_read == rows.nbytes
+    X, y = pipe.read_all()          # the one sanctioned access
+    assert X.shape == (40, 4) and y.shape == (40,)
+    assert pipe.stats.bytes_read == X.nbytes + y.nbytes
 
 
 def test_densify_matches_manual_scatter(corpus):
