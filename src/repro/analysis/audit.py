@@ -297,6 +297,11 @@ def _resident_unit(plan_: ExecutionPlan) -> List[_Unit]:
             shardings = (rep, rep)
         X = _sds((lrows, n), jnp.float32, shardings[0])
         y = _sds((lrows,), jnp.float32, shardings[1])
+    elif plan_.fmt == CSR:
+        # the padded-ELL pair the resident-sparse engine stages
+        X = (_sds((rows, plan_.kmax), jnp.int32),
+             _sds((rows, plan_.kmax), jnp.float32))
+        y = _sds((rows,), jnp.float32)
     else:
         X = _sds((lrows, n), jnp.float32)
         y = _sds((lrows,), jnp.float32)
@@ -310,21 +315,21 @@ def _resident_unit(plan_: ExecutionPlan) -> List[_Unit]:
         return epoch_fn.func.lower(*epoch_fn.args, state, X, y, key,
                                    **epoch_fn.keywords)
 
-    data_global = _aval_bytes(X) + _aval_bytes(y)
-    pad = data_global - rows * (n + 1) * 4
+    xs = X if isinstance(X, tuple) else (X,)
+    data_global = sum(_aval_bytes(a) for a in xs) + _aval_bytes(y)
+    # record_h2d books the PRE-pad host corpus bytes (the README's
+    # bytes_staged contract); the pad is a placement artifact
+    staged = data_global if plan_.fmt == CSR else rows * (n + 1) * 4
     return [_Unit(
         name=f"resident_epoch[rows={lrows}]", lower=lower,
         scan_trips=plan_.num_batches,
         state_leaf_bytes=_leaf_bytes(state),
         data_bytes_global=data_global,
-        data_bytes_per_device=(_per_device_bytes(X, mesh)
-                               + _per_device_bytes(y, mesh)),
-        # record_h2d books the PRE-pad host corpus bytes (the README's
-        # bytes_staged contract); the pad is a placement artifact
-        model_h2d_bytes=rows * (n + 1) * 4, pad_bytes=pad,
+        data_bytes_per_device=sum(_per_device_bytes(a, mesh)
+                                  for a in xs + (y,)),
+        model_h2d_bytes=staged, pad_bytes=data_global - staged,
         donated=False, key_bytes=_aval_bytes(key),
-        data_arg_bytes=[_per_device_bytes(X, mesh),
-                        _per_device_bytes(y, mesh)])]
+        data_arg_bytes=[_per_device_bytes(a, mesh) for a in xs + (y,)])]
 
 
 def _build_units(plan_: ExecutionPlan) -> List[_Unit]:

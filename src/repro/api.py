@@ -19,8 +19,8 @@ from .checkpoint.checkpointer import (  # noqa: F401
     Checkpointer, CheckpointPolicy)
 from .core.experiment import (  # noqa: F401
     ARRAYS, AUTO, BACKENDS, CSR, DENSE, EAGER, FUSED, GATHER, LOSSES, PSUM,
-    RESIDENT, RESIDENT_EAGER, RESIDENT_FUSED, SHARDED_RESIDENT,
-    SHARDED_STREAMED, SPARSE_CSR, STREAMED, STREAMED_EAGER,
+    RESIDENT, RESIDENT_EAGER, RESIDENT_FUSED, RESIDENT_SPARSE,
+    SHARDED_RESIDENT, SHARDED_STREAMED, SPARSE_CSR, STREAMED, STREAMED_EAGER,
     DataSource, ExecutionPlan, ExperimentSpec, PlanError, RunResult,
     execute, plan, resume_from, run_experiment)
 from .core.samplers import CYCLIC, RANDOM, SCHEMES, SYSTEMATIC  # noqa: F401
@@ -37,7 +37,8 @@ from .service import ExperimentService, Outcome, serve  # noqa: F401
 __all__ = [
     "ARRAYS", "AUTO", "BACKENDS", "CSR", "DENSE", "EAGER", "FUSED",
     "GATHER", "LOSSES", "PSUM", "RESIDENT", "RESIDENT_EAGER",
-    "RESIDENT_FUSED", "SHARDED_RESIDENT", "SHARDED_STREAMED", "SPARSE_CSR",
+    "RESIDENT_FUSED", "RESIDENT_SPARSE", "SHARDED_RESIDENT",
+    "SHARDED_STREAMED", "SPARSE_CSR",
     "STREAMED", "STREAMED_EAGER",
     "CYCLIC", "RANDOM", "SCHEMES", "SYSTEMATIC",
     "ChunkImportance", "Cyclic", "Random", "Scheme", "StochasticBatch",

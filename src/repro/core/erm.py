@@ -21,6 +21,28 @@ LOGISTIC = "logistic"
 SQUARE = "square"
 SMOOTH_HINGE = "smooth_hinge"
 
+ELL_BLOCK_ROWS = 8192          # rows per block of a whole-corpus ELL pass
+
+
+def _ell_blocks(fn: Callable, init, cols: jax.Array, vals: jax.Array,
+                y: jax.Array):
+    """Fold ``fn(acc, cols_b, vals_b, y_b, keep)`` over an ELL corpus in
+    blocks of :data:`ELL_BLOCK_ROWS` rows.  The last block is moved back to
+    end at the last row; ``keep`` masks the rows an earlier block took."""
+    l, kmax = cols.shape
+    bs = min(ELL_BLOCK_ROWS, l)
+
+    def body(acc, k):
+        s = jnp.minimum(k * bs, l - bs)
+        c = jax.lax.dynamic_slice(cols, (s, 0), (bs, kmax))
+        v = jax.lax.dynamic_slice(vals, (s, 0), (bs, kmax))
+        yb = jax.lax.dynamic_slice(y, (s,), (bs,))
+        keep = s + jnp.arange(bs) >= k * bs
+        return fn(acc, c, v, yb, keep), None
+
+    acc, _ = jax.lax.scan(body, init, jnp.arange(-(-l // bs)))
+    return acc
+
 
 def _margin_losses(loss: str) -> Callable[[jax.Array, jax.Array], jax.Array]:
     """Per-example loss as a function of (z = w.x, y)."""
@@ -129,6 +151,33 @@ class ERMProblem:
     def ell_batch_grad_data(self, w: jax.Array, cols: jax.Array,
                             vals: jax.Array, yb: jax.Array) -> jax.Array:
         return jax.grad(self.ell_data_objective)(w, cols, vals, yb)
+
+    # ---- whole padded-ELL corpus, in row blocks -------------------------
+    # A corpus held on the device as (l, kmax) ELL arrays is scored block by
+    # block, so no pass materializes all (l, kmax) products at once.
+
+    def ell_objective(self, w: jax.Array, cols: jax.Array, vals: jax.Array,
+                      y: jax.Array) -> jax.Array:
+        """Full objective (mean loss + l2 term) over an ELL corpus."""
+        def loss(acc, c, v, yb, keep):
+            per = _margin_losses(self.loss)(self.ell_margins(w, c, v), yb)
+            return acc + jnp.sum(jnp.where(keep, per, 0.0))
+        total = _ell_blocks(loss, jnp.zeros((), w.dtype), cols, vals, y)
+        return total / cols.shape[0] + 0.5 * self.reg * jnp.dot(w, w)
+
+    def ell_full_grad(self, w: jax.Array, cols: jax.Array, vals: jax.Array,
+                      y: jax.Array, data_term_only: bool = False) -> jax.Array:
+        """Full gradient over an ELL corpus (the data term's mean, plus
+        ``reg * w`` unless ``data_term_only``)."""
+        def grad(acc, c, v, yb, keep):
+            def part(u):
+                per = _margin_losses(self.loss)(self.ell_margins(u, c, v),
+                                                yb)
+                return jnp.sum(jnp.where(keep, per, 0.0))
+            return acc + jax.grad(part)(w)
+        g = _ell_blocks(grad, jnp.zeros_like(w), cols, vals,
+                        y) / cols.shape[0]
+        return g if data_term_only else g + self.reg * w
 
     # ---- theory constants (Assumptions 1 & 2) ---------------------------
     def lipschitz(self, X: jax.Array) -> jax.Array:

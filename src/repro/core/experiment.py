@@ -79,14 +79,18 @@ STREAMED_EAGER = "streamed-eager"    # DataPipeline + chunked epoch engine
 SPARSE_CSR = "sparse-csr"            # SparsePipeline + sparse chunked engine
 RESIDENT_EAGER = "resident-eager"    # in-graph epochs, gather/dynamic_slice
 RESIDENT_FUSED = "resident-fused"    # in-graph epochs, fused Pallas kernels
+RESIDENT_SPARSE = "resident-sparse"  # CSR staged whole as padded ELL
 SHARDED_STREAMED = "sharded-streamed"  # chunks sharded across a device mesh
 SHARDED_RESIDENT = "sharded-resident"  # corpus sharded across a device mesh
 BACKENDS = (STREAMED_EAGER, SPARSE_CSR, RESIDENT_EAGER, RESIDENT_FUSED,
-            SHARDED_STREAMED, SHARDED_RESIDENT)
+            RESIDENT_SPARSE, SHARDED_STREAMED, SHARDED_RESIDENT)
 
 # resident-placement budget on the CPU backend, which reports no memory
 # stats: stage corpora up to this size, stream anything larger
 DEFAULT_RESIDENT_BUDGET = 1 << 30
+# the TPU tiles an array's minor dimension in lanes of this width: a
+# resident (rows, kmax) ELL array holds kmax rounded up to it
+_LANES = 128
 # per staged chunk when spec.chunk is unset (matches the benchmark's
 # historical default)
 _CHUNK_BYTE_BUDGET = 64 << 20
@@ -314,8 +318,8 @@ def _probe(data: DataSource) -> _Probe:
 def _fused_support(spec: ExperimentSpec, probe: _Probe) -> Tuple[bool, str]:
     """(supported, reason-if-not) for the fused Pallas gradient kernels."""
     if probe.fmt == CSR:
-        return False, ("fused kernels are dense-only; CSR corpora keep the "
-                       "sparse chunked engine")
+        return False, ("fused kernels are dense-only; CSR corpora run the "
+                       "padded-ELL engines (sparse-csr, resident-sparse)")
     try:
         from ..kernels import fused_erm  # pallas availability
     except ImportError:
@@ -340,6 +344,14 @@ def _resident_budget(spec: ExperimentSpec) -> int:
             "or force placement=")
     # leave headroom for solver state, staging and compiler scratch
     return int(limit * 0.6)
+
+
+def _ell_resident_bytes(probe: _Probe) -> int:
+    """Device bytes of a CSR corpus staged as padded ELL: int32 column ids
+    and float32 values of ``rows x kmax``, kmax rounded up to the lanes,
+    plus the float32 labels."""
+    slots = probe.rows * (-(-probe.kmax // _LANES) * _LANES)
+    return slots * 8 + probe.rows * 4
 
 
 def plan(spec: ExperimentSpec, *, audit: bool = False) -> ExecutionPlan:
@@ -457,8 +469,8 @@ def plan(spec: ExperimentSpec, *, audit: bool = False) -> ExecutionPlan:
         if probe.fmt == CSR:
             raise PlanError(
                 "sharded placement splits dense (l, n) chunks on the batch "
-                "axis; CSR corpora keep the single-host sparse engine "
-                "(sharded CSR staging is a ROADMAP follow-on)")
+                "axis; CSR corpora run single-device ELL engines, streamed "
+                "or resident (sharded CSR staging is a ROADMAP follow-on)")
         if spec.kernel == FUSED:
             raise PlanError(
                 "kernel='fused' rejected under a >1-device mesh: the fused "
@@ -508,12 +520,23 @@ def plan(spec: ExperimentSpec, *, audit: bool = False) -> ExecutionPlan:
         why.append("arrays are device-resident by construction")
     elif probe.fmt == CSR:
         if spec.placement == RESIDENT:
-            raise PlanError(
-                "resident placement stages a dense (l, n) corpus; CSR "
-                "corpora run the streamed sparse engine (sparse resident "
-                "mode is a ROADMAP follow-on)")
-        placement = STREAMED
-        why.append("CSR corpus → streamed sparse engine")
+            ell, budget = _ell_resident_bytes(probe), _resident_budget(spec)
+            if ell > budget:
+                raise PlanError(
+                    f"resident placement stages the CSR corpus as padded "
+                    f"ELL, {ell / 1e6:.1f} MB ({probe.rows} rows x "
+                    f"{probe.kmax} slots, lanes of {_LANES}), over the "
+                    f"{budget / 1e6:.0f} MB device budget; stream it "
+                    f"(placement='streamed')")
+            placement = RESIDENT
+            why.append(f"CSR corpus, resident placement forced → staged "
+                       f"once as padded ELL, {ell / 1e6:.1f} MB within the "
+                       f"{budget / 1e6:.0f} MB device budget: the "
+                       "resident-sparse engine")
+        else:
+            placement = STREAMED
+            why.append("CSR corpus → streamed sparse engine (resident "
+                       "placement must be forced)")
     elif scheme_obj.adaptive:
         placement = STREAMED
         why.append(f"{scheme_obj.name} sampling picks batches on the host "
@@ -634,7 +657,7 @@ def plan(spec: ExperimentSpec, *, audit: bool = False) -> ExecutionPlan:
                        sparse=(probe.fmt == CSR))
 
     if probe.fmt == CSR:
-        backend = SPARSE_CSR
+        backend = RESIDENT_SPARSE if placement == RESIDENT else SPARSE_CSR
     elif shards > 1:
         backend = (SHARDED_RESIDENT if placement == RESIDENT
                    else SHARDED_STREAMED)
@@ -1235,6 +1258,14 @@ def _block_loss_sum(problem: ERMProblem, n: int, w: jax.Array,
     return total + problem.sum_margin_loss(z, rows[:, n])
 
 
+@partial(jax.jit, static_argnames=("problem",))
+def _ell_objective_jit(problem: ERMProblem, w: jax.Array, X, y: jax.Array
+                       ) -> jax.Array:
+    # the full objective over a resident ELL corpus X = (cols, vals), in
+    # row blocks on the device: one host read per pass
+    return problem.ell_objective(w, *X, y)
+
+
 def _warm_dense_objective(problem: ERMProblem, n: int, rows: int,
                           block_rows: int, w) -> None:
     """Compile :func:`_block_loss_sum` for every block length a pass over
@@ -1323,9 +1354,10 @@ def _drive_resident(plans: List[ExecutionPlan],
                     resumes: List[Optional[RunResult]], epochs: int,
                     tracer: Tracer, tracers: List[Tracer]) -> List[RunResult]:
     """The resident engine: read the corpus once, stage it whole on the
-    device (sharded across the mesh when the plan has one), then run each
-    epoch in one device call per cell.  The per-epoch objective and the
-    checkpoints run outside the timers."""
+    device (sharded across the mesh when the plan has one; a CSR corpus as
+    padded-ELL ``(cols, vals)``), then run each epoch in one device call
+    per cell.  The per-epoch objective and the checkpoints run outside the
+    timers."""
     from ..data import pipeline as pipemod
 
     ref = plans[0]
@@ -1347,30 +1379,37 @@ def _drive_resident(plans: List[ExecutionPlan],
             X = jnp.asarray(spec.data.X, jnp.float32)
             y = jnp.asarray(spec.data.y, jnp.float32)
     else:
-        pipe = pipemod.DataPipeline(pipemod.PipelineConfig(
+        if ref.fmt == CSR:
+            from ..data.sparse import SparsePipeline as pipe_cls
+        else:
+            pipe_cls = pipemod.DataPipeline
+        pipe = pipe_cls(pipemod.PipelineConfig(
             corpus=spec.data.path, batch_size=spec.batch_size,
             sampling=spec.scheme, seed=spec.seed, prefetch=0, resident=True),
             tracer=tracer)
         stats = pipe.stats
-        # contiguous X and y straight off the file, in one pass (the read
-        # lays them out): nothing is copied inside the H2D timer
-        Xh, yh = pipe.read_all()
+        # the corpus straight off the file, in one pass that lays it out:
+        # contiguous X and y, or a CSR corpus padded to ELL (cols, vals, y).
+        # Nothing is copied inside the H2D timer
+        host = pipe.read_all()
+        nbytes = sum(a.nbytes for a in host)
         if sharded:
-            X, y, h2d_dt = _stage_resident_sharded(ref, Xh, yh, stats,
-                                                   tracer)
+            X, y, h2d_dt = _stage_resident_sharded(ref, *host, stats, tracer)
         else:
-            with tracer.timespan("stage_resident", H2D,
-                                 bytes=Xh.nbytes + yh.nbytes) as sp:
+            fmt = {"fmt": "ell"} if ref.fmt == CSR else {}
+            with tracer.timespan("stage_resident", H2D, bytes=nbytes,
+                                 **fmt) as sp:
                 # lint: allow[REPRO002] the accounted staging site:
                 # the span IS the measurement record_h2d books below
-                X, y = jax.block_until_ready((jax.device_put(Xh),
-                                              jax.device_put(yh)))
+                *X, y = jax.block_until_ready(
+                    tuple(jax.device_put(a) for a in host))
             h2d_dt = sp.dur
-            stats.record_h2d(h2d_dt, Xh.nbytes + yh.nbytes)
+            stats.record_h2d(h2d_dt, nbytes)
+            X = tuple(X) if ref.fmt == CSR else X[0]
         # the host copies are dead once staged: free their pages here, on
         # the driver's clock, and not unseen at return
-        with tracer.span("release", DRIVER, bytes=Xh.nbytes + yh.nbytes):
-            del Xh, yh
+        with tracer.span("release", DRIVER, bytes=nbytes):
+            del host
 
     # 'psum' keeps the padded corpus sharded through the scan, so the epoch
     # engine needs the true row count (schedule, clamping, masked snapshot
@@ -1386,6 +1425,8 @@ def _drive_resident(plans: List[ExecutionPlan],
         problem = plans[i].spec.problem
         if psum:
             return _masked_objective_jit(problem, ref.rows, w, X, y)
+        if ref.fmt == CSR:
+            return _ell_objective_jit(problem, w, X, y)
         return _objective_jit(problem, w, X, y)
 
     with tracer.span("init", DRIVER):
@@ -1425,9 +1466,12 @@ def _drive_resident(plans: List[ExecutionPlan],
 
     # which fused kernel the epochs run, and the row DMAs an RS epoch's
     # fused_grad_rows calls issue (counters fused.row_dmas / _bytes); fused
-    # plans never share a corpus, so ref is the only cell
+    # plans never share a corpus, so ref is the only cell.  An ELL corpus
+    # runs the eager gather/scatter epoch (``kernel=ell``).
     kernel_attr, row_dmas, row_dma_bytes = {}, 0, 0
-    if ref.kernel == FUSED:
+    if ref.fmt == CSR:
+        kernel_attr = {"kernel": "ell"}
+    elif ref.kernel == FUSED:
         kernel_attr = {"kernel": batch_access(ref.scheme_name)}
         if kernel_attr["kernel"] == "rows":
             row_dmas, row_dma_bytes = fused_row_dmas(

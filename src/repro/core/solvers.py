@@ -28,7 +28,8 @@ longer exported from ``repro.core``):
   epoch engine: ONE device call scans K staged batches with donated solver
   state, amortizing per-batch Python dispatch K-fold.
 * :func:`make_resident_epoch_fn` — fused host mode: the whole corpus staged
-  on device once, epochs driven in-graph.
+  on device once (dense rows, or a CSR corpus as padded-ELL arrays), epochs
+  driven in-graph.
 
 Set ``SolverConfig(use_fused=True)`` to route device-resident gradients
 through the fused Pallas kernels (``repro.kernels.fused_erm``): the sampled
@@ -283,9 +284,14 @@ def epoch_begin(problem: ERMProblem, cfg: SolverConfig, state: SolverState,
 @partial(jax.jit, static_argnames=("problem", "cfg", "scheme", "batch_size",
                                    "rows"))
 def _run_one_epoch(problem: ERMProblem, cfg: SolverConfig, scheme: str,
-                   batch_size: int, state: SolverState, X: jax.Array,
-                   y: jax.Array, key: jax.Array,
+                   batch_size: int, state: SolverState, X, y: jax.Array,
+                   key: jax.Array,
                    rows: Optional[int] = None) -> SolverState:
+    # ``X`` is the dense (l, n) corpus or, with ``cfg.sparse``, the padded-
+    # ELL pair ``(cols, vals)`` of (l, kmax) arrays (:func:`_ell_epoch`).
+    if cfg.sparse:
+        return jax.named_call(_ell_epoch, name="ell_resident_epoch")(
+            problem, cfg, scheme, batch_size, state, X, y, key)
     # ``rows`` (static) is the TRUE corpus length when X/y carry zero-row
     # padding (the sharded 'psum' placement pads so the corpus shards evenly
     # across the mesh).  The sampler schedule runs over ``rows``; block
@@ -343,6 +349,48 @@ def _run_one_epoch(problem: ERMProblem, cfg: SolverConfig, scheme: str,
     # ls-mode parity surface (tests pin seq == vec trajectories bit-exact),
     # and unrolling one mode but not the other changes XLA fusion enough
     # to shift shared arithmetic by ulps
+    state, _ = jax.lax.scan(body, state, jnp.arange(m))
+    return state
+
+
+def _ell_epoch(problem: ERMProblem, cfg: SolverConfig, scheme: str,
+               batch_size: int, state: SolverState, X, y: jax.Array,
+               key: jax.Array) -> SolverState:
+    """One resident epoch over a padded-ELL corpus ``X = (cols, vals)``:
+    each batch is selected in-graph, one ``dynamic_slice`` of ``(b, kmax)``
+    rows per CS/SS batch or one row gather per RS batch, then updated by
+    :func:`sparse_batch_step`.  Snapshot solvers refresh their full
+    gradient over the whole ELL corpus in the same call."""
+    cols, vals = X
+    l, kmax = cols.shape
+    m = samplers.num_batches(l, batch_size)
+    if _needs_snapshot(cfg.solver):
+        data_only = cfg.solver == SAAG2
+        state = epoch_begin(
+            problem, cfg, state, lambda w: problem.ell_full_grad(
+                w, cols, vals, y, data_term_only=data_only))
+
+    contiguous = batch_access(scheme) == "block"
+    if contiguous:
+        starts = samplers.batch_slice_starts(scheme, key, l, batch_size)
+    else:
+        idx_mat = samplers.epoch_indices(scheme, key, l, batch_size)
+
+    def body(st, j):
+        if contiguous:
+            # ONE contiguous block of ELL rows per batch (CS/SS); the slice
+            # clamps a block past the end to the last batch_size rows
+            cb = jax.lax.dynamic_slice(cols, (starts[j], 0),
+                                       (batch_size, kmax))
+            vb = jax.lax.dynamic_slice(vals, (starts[j], 0),
+                                       (batch_size, kmax))
+            yb = jax.lax.dynamic_slice(y, (starts[j],), (batch_size,))
+        else:
+            idx = idx_mat[j]
+            cb, vb = jnp.take(cols, idx, axis=0), jnp.take(vals, idx, axis=0)
+            yb = jnp.take(y, idx, axis=0)
+        return sparse_batch_step(problem, cfg, st, cb, vb, yb, j), None
+
     state, _ = jax.lax.scan(body, state, jnp.arange(m))
     return state
 
@@ -507,7 +555,8 @@ def make_resident_epoch_fn(problem: ERMProblem, cfg: SolverConfig,
                            scheme: str, batch_size: int,
                            rows: Optional[int] = None):
     """Fused host mode: ``(state, X, y, key) -> state`` with the WHOLE corpus
-    resident on device (``PipelineConfig.resident``).
+    resident on device (``PipelineConfig.resident``).  With ``cfg.sparse``
+    ``X`` is the padded-ELL pair ``(cols, vals)`` of a CSR corpus.
 
     Batch selection happens in-graph — ``batch_slice_starts`` drives one
     ``dynamic_slice`` per CS/SS batch, ``epoch_indices`` one gather per RS
@@ -519,10 +568,11 @@ def make_resident_epoch_fn(problem: ERMProblem, cfg: SolverConfig,
     ``rows`` is the true corpus length when the staged arrays are zero-row
     padded (the sharded 'psum' placement); see :func:`_run_one_epoch`.
     """
-    if cfg.sparse:
+    if cfg.sparse and (cfg.use_fused or rows is not None):
         raise ValueError(
-            "resident mode stages a dense (l, n) corpus; CSR corpora keep "
-            "the host-driven sparse epoch engine")
+            "the resident ELL engine runs single-device on the eager "
+            "gather/scatter path: the fused kernels read dense rows, and a "
+            "padded (sharded 'psum') ELL corpus is not staged")
     if rows is not None and cfg.use_fused:
         raise ValueError(
             "use_fused samples with the kernels' own end-of-corpus clamping, "

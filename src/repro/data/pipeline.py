@@ -37,6 +37,23 @@ from ..obs import ACCESS, H2D, NULL_TRACER, WAIT
 from .dataset import CorpusMeta, host_shard, open_corpus
 
 READ_BLOCK_BYTES = 16 << 20   # file bytes per block of a resident read
+READ_WORKERS = 8              # most threads a resident read runs on
+
+
+def block_workers(blocks: int) -> int:
+    """Threads for a resident pass over ``blocks`` blocks: the usable CPUs,
+    at most :data:`READ_WORKERS`, and no more than there are blocks."""
+    return max(1, min(len(os.sched_getaffinity(0)), READ_WORKERS, blocks))
+
+
+def run_blocks(fn, starts, workers: int) -> None:
+    """``fn(start)`` for each block start over ``workers`` threads (numpy's
+    copies release the GIL); a worker's error is raised here.  Workers
+    emit no spans: a span opened on a pool thread would be a top-level
+    span of its own and count twice in the lane totals."""
+    with ThreadPoolExecutor(workers) as pool:
+        for _ in pool.map(fn, starts):
+            pass
 
 
 @dataclasses.dataclass
@@ -322,15 +339,13 @@ class DataPipeline(PrefetchPipeline):
         n = mm.shape[1] - 1
         step = max(1, READ_BLOCK_BYTES // (mm.shape[1] * mm.itemsize))
         starts = range(0, rows, step)
-        workers = max(1, min(len(os.sched_getaffinity(0)), 8, len(starts)))
+        workers = block_workers(len(starts))
 
         def copy(a: int) -> None:
             b = min(a + step, rows)
             X[a:b] = mm[lo + a:lo + b, :n]
             y[a:b] = mm[lo + a:lo + b, n]
 
-        # workers emit no spans: a span opened on a pool thread would be a
-        # top-level span of its own and count twice in the lane totals
         with self.tracer.timespan("read_all", ACCESS,
                                   scheme=self.scheme.name,
                                   blocks=len(starts),
@@ -339,9 +354,7 @@ class DataPipeline(PrefetchPipeline):
             # read to the device_put that follows, booking disk time as H2D
             X = np.empty((rows, n), mm.dtype)
             y = np.empty(rows, mm.dtype)
-            with ThreadPoolExecutor(workers) as pool:
-                for _ in pool.map(copy, starts):   # re-raises a worker's error
-                    pass
+            run_blocks(copy, starts, workers)
             sp.set(bytes=X.nbytes + y.nbytes)
         self.stats.record(sp.dur, X.nbytes + y.nbytes)
         if self.tracer.enabled:

@@ -53,7 +53,8 @@ from ..core import samplers, schemes
 from ..core.erm import LOGISTIC, SMOOTH_HINGE, SQUARE
 from ..obs import ACCESS, CONVERT, NULL_TRACER
 from .dataset import CorpusMeta, host_shard
-from .pipeline import AccessStats, PipelineConfig, PrefetchPipeline
+from .pipeline import (READ_BLOCK_BYTES, AccessStats, PipelineConfig,
+                       PrefetchPipeline, block_workers, run_blocks)
 
 CSR_KIND = "sparse_rows"
 
@@ -442,6 +443,73 @@ class SparsePipeline(PrefetchPipeline):
         if self.scheme.adaptive:
             return batch, bi.j, bi.weight
         return batch
+
+    # ---- resident mode ----------------------------------------------------
+    def read_all(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """The whole host shard as padded-ELL ``(cols, vals, y)``:
+        ``(rows, kmax)`` int32 and float32, and ``(rows,)`` float32.
+
+        Resident mode (``PipelineConfig.resident``): the caller stages these
+        on the device once.  Two passes over row blocks of about
+        ``READ_BLOCK_BYTES`` of file, each over a small thread pool:
+
+        * read (span ``access:read_csr``, booked in ``stats``): the row
+          pointers, then each block's column ids, values and labels copied
+          from the memmaps into contiguous host arrays;
+        * convert (span ``convert:ell_resident``): each block's row segments
+          padded into its rows of ``cols`` and ``vals`` (``_pad_segments``).
+
+        Counters ``ell_resident.nonzeros``, ``.slots`` (rows x kmax) and
+        ``.bytes`` (the three arrays) count the padded corpus once.
+        """
+        if self._thread is not None and self._thread.is_alive():
+            raise RuntimeError(
+                "prefetch producer is active; resident staging and batch "
+                "streaming are mutually exclusive on one pipeline")
+        csr, lo, rows, kmax = self.csr, self.lo, self.hi - self.lo, self.kmax
+        with self.tracer.timespan("read_csr", ACCESS,
+                                  scheme=self.scheme.name) as sp:
+            ptr = np.array(csr.indptr[lo:self.hi + 1])
+            base, nnz = int(ptr[0]), int(ptr[-1] - ptr[0])
+            row_bytes = (nnz * self._itemsize) / max(rows, 1) + 12
+            step = max(1, int(READ_BLOCK_BYTES // row_bytes))
+            starts = range(0, rows, step)
+            workers = block_workers(len(starts))
+            flat_c = np.empty(nnz, np.int32)
+            flat_v = np.empty(nnz, np.float32)
+            y = np.empty(rows, np.float32)
+
+            def read(a: int) -> None:
+                b = min(a + step, rows)
+                s, e = int(ptr[a]), int(ptr[b])
+                flat_c[s - base:e - base] = csr.indices[s:e]
+                flat_v[s - base:e - base] = csr.values[s:e]
+                y[a:b] = csr.labels[lo + a:lo + b]
+
+            run_blocks(read, starts, workers)
+            nbytes = nnz * self._itemsize + ptr.nbytes + y.nbytes
+            sp.set(bytes=nbytes, blocks=len(starts), workers=workers)
+        self.stats.record(sp.dur, nbytes)
+        with self.tracer.span("ell_resident", CONVERT, nnz=nnz,
+                              slots=rows * kmax):
+            cols = np.empty((rows, kmax), np.int32)
+            vals = np.empty((rows, kmax), np.float32)
+
+            def pad(a: int) -> None:
+                b = min(a + step, rows)
+                seg = ptr[a:b + 1] - base
+                cols[a:b], vals[a:b] = _pad_segments(
+                    flat_c[seg[0]:seg[-1]], flat_v[seg[0]:seg[-1]],
+                    np.diff(seg), seg[:-1] - seg[0], kmax)
+
+            run_blocks(pad, starts, workers)
+        if self.tracer.enabled:
+            m = self.tracer.metrics
+            m.counter("ell_resident.nonzeros").inc(nnz)
+            m.counter("ell_resident.slots").inc(rows * kmax)
+            m.counter("ell_resident.bytes").inc(
+                cols.nbytes + vals.nbytes + y.nbytes)
+        return cols, vals, y
 
 
 # ---------------------------------------------------------------------------

@@ -407,6 +407,7 @@ READERS = {"objective_s_per_epoch": 3, "warmup_s": 3, "untraced_s": 3,
 # cell -> (rows, features, batch, backend the CPU planner picks)
 SMALL_CELLS = {"higgs-resident-ss": (6000, 28, 200, "resident-eager"),
                "higgs-streamed-rs": (6000, 28, 200, "streamed-eager"),
+               "higgs-streamed-ss": (6000, 28, 200, "streamed-eager"),
                "rcv1-streamed-ss": (3001, 2000, 100, "sparse-csr")}
 
 

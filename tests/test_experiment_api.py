@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from repro.api import (AUTO, BACKENDS, EAGER, FUSED, RESIDENT,
-                       RESIDENT_EAGER, RESIDENT_FUSED, SHARDED_RESIDENT,
+                       RESIDENT_EAGER, RESIDENT_FUSED, RESIDENT_SPARSE,
+                       SHARDED_RESIDENT,
                        SHARDED_STREAMED, SPARSE_CSR, STREAMED,
                        STREAMED_EAGER, DataSource, ExperimentSpec, PlanError,
                        execute, plan)
@@ -96,9 +97,14 @@ def test_planner_selects_documented_backend_per_cell(dense_corpus, csr_corpus,
     # sparse × streamed (auto placement lowers to streamed)
     sp = plan(_spec(csr, solver=solver, scheme=scheme))
     assert sp.backend == SPARSE_CSR and sp.cfg.sparse
-    # sparse × resident: cannot run — rejected at plan time
-    with pytest.raises(PlanError, match="resident"):
-        plan(_spec(csr, solver=solver, scheme=scheme, placement=RESIDENT))
+    # sparse × resident: the corpus staged whole as padded ELL, eager
+    # gather/scatter epochs; the fused kernels stay dense-only
+    res = plan(_spec(csr, solver=solver, scheme=scheme, placement=RESIDENT))
+    assert res.backend == RESIDENT_SPARSE and res.cfg.sparse
+    assert res.kernel == EAGER and not res.cfg.use_fused
+    with pytest.raises(PlanError, match="dense-only"):
+        plan(_spec(csr, solver=solver, scheme=scheme, placement=RESIDENT,
+                   kernel=FUSED))
 
 
 def test_sharded_backends_are_first_class(dense_corpus):
@@ -225,7 +231,8 @@ def test_plan_describe_names_the_decision(dense_corpus):
 
 @pytest.mark.parametrize("kw,match", [
     (dict(kernel=FUSED), "dense-only"),                       # sparse+fused
-    (dict(placement=RESIDENT), "resident"),                   # sparse+resident
+    # sparse+resident whose padded ELL exceeds the device budget
+    (dict(placement=RESIDENT, resident_budget=1024), "resident"),
     # sparse + line_search on the fused path: the combo that used to fall
     # back silently; the CSR conflict is reported first and that's fine —
     # what matters is a clear plan-time rejection

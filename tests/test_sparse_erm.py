@@ -320,10 +320,18 @@ def test_run_rejects_sparse_config(dense):
 
 
 def test_resident_epoch_fn_rejects_sparse():
+    """The resident ELL engine runs single-device on the eager path: a
+    sparse config with the fused kernels, or over a padded (sharded
+    'psum') corpus, is refused."""
+    with pytest.raises(ValueError, match="resident"):
+        solvers.make_resident_epoch_fn(ERMProblem(),
+                                       SolverConfig(sparse=True,
+                                                    use_fused=True),
+                                       samplers.CYCLIC, B)
     with pytest.raises(ValueError, match="resident"):
         solvers.make_resident_epoch_fn(ERMProblem(),
                                        SolverConfig(sparse=True),
-                                       samplers.CYCLIC, B)
+                                       samplers.CYCLIC, B, rows=ROWS)
 
 
 @pytest.mark.parametrize("scheme", samplers.SCHEMES)
